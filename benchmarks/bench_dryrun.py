@@ -42,8 +42,6 @@ E2E_MESH = (2, 4)                     # (data, model) on 8 host devices
 # ---------------------------------------------------------------------------
 
 def rows(mesh="single"):
-    # lazy import: repro.launch.dryrun pins XLA_FLAGS for the 512-device
-    # sweep at import time; only the cached-cell section needs it.
     from repro.launch.dryrun import RESULTS_DIR, roofline_from_cell
     out = []
     for path in sorted(glob.glob(os.path.join(RESULTS_DIR, f"*__{mesh}.json"))):
@@ -136,7 +134,10 @@ def _e2e_worker(smoke: bool) -> None:
 
 def e2e_rows(smoke: bool = False) -> list:
     env = dict(os.environ)
+    # virtual CPU devices; CPU-only so a parent holding the chip (e.g.
+    # benchmarks/run.py on a TPU host) never shares it with this child
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(ROOT, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     cmd = [sys.executable, os.path.abspath(__file__), "--e2e-worker"]

@@ -115,7 +115,10 @@ def _worker(smoke: bool) -> None:
 def main(csv=True, smoke: bool = False):
     """Spawn the 8-device worker and relay its CSV rows."""
     env = dict(os.environ)
+    # virtual CPU devices; CPU-only so a parent holding the chip (e.g.
+    # benchmarks/run.py on a TPU host) never shares it with this child
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(ROOT, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     cmd = [sys.executable, os.path.abspath(__file__), "--worker"]

@@ -1,5 +1,10 @@
 """qwen3-4b [dense]: 36L d=2560 32H (GQA kv=8, head_dim 128) d_ff=9728
-vocab=151936 — qk_norm, no qkv bias. [hf:Qwen/Qwen3-8B; hf]"""
+vocab=151936 — qk_norm, no qkv bias, rope_theta 1e6. [hf:Qwen/Qwen3-4B
+config.json]
+
+Departure, listed and kept: Qwen3-4B ties its input embedding and output
+head (``tie_word_embeddings: true``); this repo keeps a separate
+``lm_head`` (vocab x d, about 0.39B more parameters, 0.78 GB in bf16)."""
 import jax.numpy as jnp
 
 from repro.models import TransformerConfig, transformer

@@ -52,6 +52,23 @@ from repro.core.pallas_bridge import pow2_floor
 from repro.runtime import compat
 
 NEG_INF = -1e30  # avoid nan from (-inf) - (-inf)
+# Mosaic tiles the last two dims of every block by (8, 128); a per-row f32
+# vector such as lse/delta therefore travels to and from the kernels
+# broadcast across one lane-dense 128-wide tile, (BH, S, LANES), and the
+# wrappers slice or broadcast it back to (BH, S).
+LANES = 128
+
+
+def _out_struct(shape, dtype, *like) -> jax.ShapeDtypeStruct:
+    """Out-shape typed with the varying mesh axes of ``like`` — inside
+    shard_map (the ring's per-hop fold) pallas_call requires the vma."""
+    axes = frozenset().union(*(compat.vma(x) for x in like))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=axes)
+
+
+def _lanes(x: jax.Array) -> jax.Array:
+    """(..., S) f32 -> (..., S, LANES): the kernels' lse/delta layout."""
+    return jnp.broadcast_to(x[..., None], (*x.shape, LANES))
 
 
 # ---------------------------------------------------------------------------
@@ -204,7 +221,8 @@ def _fa_fwd_kernel(sched_ref, offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l = l_ref[...]
         safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(safe)
+        lse = m_ref[...] + jnp.log(safe)
+        lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
 
 
 def _as_offs(q_offset, k_offset) -> jax.Array:
@@ -274,7 +292,8 @@ def flash_attention_fwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=[
             pl.BlockSpec((1, block_q, Dh),
                          lambda h, p, sr, orf: (h, sr[p, 0], 0)),
-            pl.BlockSpec((1, block_q), lambda h, p, sr, orf: (h, sr[p, 0])),
+            pl.BlockSpec((1, block_q, LANES),
+                         lambda h, p, sr, orf: (h, sr[p, 0], 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
@@ -282,13 +301,14 @@ def flash_attention_fwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, Dh), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype),
-                   jax.ShapeDtypeStruct((BH, Sq), jnp.float32)],
+        out_shape=[_out_struct((BH, Sq, Dh), q.dtype, q, k, v),
+                   _out_struct((BH, Sq, LANES), jnp.float32, q, k, v)],
         interpret=interpret,
     )(jnp.asarray(sched), _as_offs(q_offset, k_offset), q, k, v)
+    return o, lse[..., 0]
 
 
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -339,14 +359,14 @@ def _fa_bwd_dq_kernel(sched_ref, offs_ref, q_ref, k_ref, v_ref, do_ref,
     # p from the saved lse — the PSum re-stream.  The explicit mask guard
     # matters: a fully-masked row has lse == NEG_INF and exp(s - lse)
     # would resurrect masked entries as exp(0) = 1.
-    p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, None]), 0.0)
+    p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, :1]), 0.0)
 
     do = do_ref[0].astype(jnp.float32)                 # (block_q, d)
     dp = jax.lax.dot_general(
         do, v_ref[0].astype(jnp.float32),
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)            # (block_q, block_k)
-    ds = p * (dp - delta_ref[0][:, None]) * scale
+    ds = p * (dp - delta_ref[0][:, :1]) * scale
     acc_ref[...] += jax.lax.dot_general(
         ds, k.astype(jnp.float32),
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -370,39 +390,46 @@ def _fa_bwd_dkv_kernel(sched_ref, offs_ref, q_ref, k_ref, v_ref, do_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    G = q_ref.shape[1]
-    q = q_ref[0].astype(jnp.float32)    # (G, block_q, d) — the whole group
+    # Transposed frame: tiles are (block_k, block_q), so the per-q-row
+    # lse/delta arrive lane-major, (1, block_q), and both accumulations
+    # are plain matmuls: dv += p^T @ do, dk += ds^T @ q.
     k = k_ref[0].astype(jnp.float32)    # (block_k, d)
-    s = jax.lax.dot_general(
-        q, k, dimension_numbers=(((2,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # (G, block_q, block_k)
-
+    v = v_ref[0].astype(jnp.float32)
     loc_k = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (G, block_q, block_k), 2)
+        jnp.int32, (block_k, block_q), 0)
     qpos = offs_ref[0] + iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (G, block_q, block_k), 1)
+        jnp.int32, (block_k, block_q), 1)
     kpos = offs_ref[1] + loc_k
     mask = loc_k < kv_len
     if causal:
         mask &= qpos >= kpos
     if window is not None:
         mask &= (qpos - kpos) < window
-    p = jnp.where(mask, jnp.exp(s - lse_ref[0][..., None]), 0.0)
 
-    do = do_ref[0].astype(jnp.float32)                 # (G, block_q, d)
-    # dv += sum over the group's q rows of p^T @ do  (the kv-stationary
-    # PSum: one accumulator per k block, q streams)
-    dv_acc[...] += jax.lax.dot_general(
-        p, do, dimension_numbers=(((0, 1), (0, 1)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(
-        do, v_ref[0].astype(jnp.float32),
-        dimension_numbers=(((2,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (G, block_q, block_k)
-    ds = p * (dp - delta_ref[0][..., None]) * scale
-    dk_acc[...] += jax.lax.dot_general(
-        ds, q, dimension_numbers=(((0, 1), (0, 1)), ((), ())),
-        preferred_element_type=jnp.float32)
+    # the whole GQA group folds into this k column's accumulators (the
+    # kv-stationary PSum: one accumulator per k block, q streams); one
+    # q head at a time, as Mosaic contracts a single dim per matmul
+    nt = (((1,), (1,)), ((), ()))
+    nn = (((1,), (0,)), ((), ()))
+    dk = jnp.zeros(dk_acc.shape, jnp.float32)
+    dv = jnp.zeros(dv_acc.shape, jnp.float32)
+    for g in range(q_ref.shape[1]):
+        q = q_ref[0, g].astype(jnp.float32)            # (block_q, d)
+        do = do_ref[0, g].astype(jnp.float32)
+        lse = lse_ref[0, pl.ds(g, 1), :]               # (1, block_q)
+        delta = delta_ref[0, pl.ds(g, 1), :]
+        s_t = jax.lax.dot_general(
+            k, q, nt, preferred_element_type=jnp.float32) * scale
+        p_t = jnp.where(mask, jnp.exp(s_t - lse), 0.0)  # (block_k, block_q)
+        dv += jax.lax.dot_general(p_t, do, nn,
+                                  preferred_element_type=jnp.float32)
+        dp_t = jax.lax.dot_general(v, do, nt,
+                                   preferred_element_type=jnp.float32)
+        ds_t = p_t * (dp_t - delta) * scale
+        dk += jax.lax.dot_general(ds_t, q, nn,
+                                  preferred_element_type=jnp.float32)
+    dk_acc[...] += dk
+    dv_acc[...] += dv
 
     @pl.when(sched_ref[p_id, 3] == 1)
     def _drain():
@@ -465,8 +492,10 @@ def flash_attention_bwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                          lambda h, p, sr, orf: (h // group, sr[p, 1], 0)),
             pl.BlockSpec((1, block_q, Dh),
                          lambda h, p, sr, orf: (h, sr[p, 0], 0)),
-            pl.BlockSpec((1, block_q), lambda h, p, sr, orf: (h, sr[p, 0])),
-            pl.BlockSpec((1, block_q), lambda h, p, sr, orf: (h, sr[p, 0])),
+            pl.BlockSpec((1, block_q, LANES),
+                         lambda h, p, sr, orf: (h, sr[p, 0], 0)),
+            pl.BlockSpec((1, block_q, LANES),
+                         lambda h, p, sr, orf: (h, sr[p, 0], 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, Dh),
                                lambda h, p, sr, orf: (h, sr[p, 0], 0)),
@@ -475,9 +504,9 @@ def flash_attention_bwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, **kern_kw),
         grid_spec=dq_spec,
-        out_shape=jax.ShapeDtypeStruct((BH, Sq, Dh), f32),
+        out_shape=_out_struct((BH, Sq, Dh), f32, q, k, v, do),
         interpret=interpret,
-    )(jnp.asarray(sched_row), offs, q, k, v, do, lse, delta)
+    )(jnp.asarray(sched_row), offs, q, k, v, do, _lanes(lse), _lanes(delta))
 
     # group-major views so one kv grid step sees its whole GQA group
     qg = q.reshape(BHkv, group, Sq, Dh)
@@ -513,8 +542,8 @@ def flash_attention_bwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, **kern_kw),
         grid_spec=dkv_spec,
-        out_shape=[jax.ShapeDtypeStruct((BHkv, Sk, Dh), f32),
-                   jax.ShapeDtypeStruct((BHkv, Sk, Dh), f32)],
+        out_shape=[_out_struct((BHkv, Sk, Dh), f32, q, k, v, do),
+                   _out_struct((BHkv, Sk, Dh), f32, q, k, v, do)],
         interpret=interpret,
     )(jnp.asarray(sched_col), offs, qg, k, v, dog, lseg, deltag)
     return dq, dk, dv

@@ -3,8 +3,8 @@
 NHWC x HWIO -> NHWC. The TEU tile maps to (a block of output rows) x (all
 columns) x (a block of output channels); the overlapping input window — the
 operand the FIFO mesh shares between neighbouring tiles in Fig. 2 — is
-expressed with an element-indexed halo block (``compat.element_block_spec``,
-``pl.Element`` on new JAX / ``pl.Unblocked`` on 0.4.x), and is REUSED across all
+expressed with an element-indexed halo block (``compat.element_block_spec``
+over ``pl.Element`` dims), and is REUSED across all
 co-blocks because the grid order puts `co` innermost of the parallel dims
 (the block's index map is invariant to `co`, so Mosaic keeps it VMEM-resident
 — the intra-chip analogue of sharing E between P and Q). The reduction

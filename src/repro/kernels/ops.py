@@ -210,8 +210,8 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     lens = jnp.repeat(lengths.astype(jnp.int32), Hkv)
     ks = vs = None
     if k_scale is not None:
-        ks = k_scale.transpose(2, 0, 1)       # (Hkv, P, page)
-        vs = v_scale.transpose(2, 0, 1)
+        ks = k_scale.transpose(0, 2, 1)       # (P, Hkv, page)
+        vs = v_scale.transpose(0, 2, 1)
     _record_dispatch("paged_flash_decode",
                      impl="int8" if k_scale is not None else "pallas",
                      batch=B, pages=P, page_size=page_size,

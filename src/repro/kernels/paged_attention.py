@@ -15,14 +15,13 @@ stationary — identical schedule to the dense decode kernel, only the
 kv-block address is indirected.
 
 The int8 path keeps the pool quantized in HBM and dequantizes one page at
-a time inside the kernel (per-(token, head) scales ride along as their own
-scalar-indexed blocks), so quantized serving never materializes an f32
-cache.
-
-On-TPU note: blocks are (page_size, Dh); with the default page_size=16 and
-Dh=128 the bf16 tiles meet the (16, 128) packing rule, while int8 pools
-want page_size >= 32 on real hardware (interpret mode, the CI path, does
-not care).
+a time inside the kernel, so quantized serving never materializes an f32
+cache.  The per-(token, head) scales ride along page-major, one
+``(Hkv, page_size)`` block per page: Mosaic tiles the last two block dims
+by (8, 128) unless they span the whole array dims, which a per-head
+``(1, page_size)`` slice would not.  The kernel picks its head's row and
+applies it along the key (lane) axis of the score and probability tiles —
+``(q . k) * s_k`` and ``(p * s_v) @ v`` equal the dequantized products.
 """
 from __future__ import annotations
 
@@ -58,14 +57,14 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     b = pl.program_id(0)
     k = k_ref[0, 0].astype(jnp.float32)     # (page_size, d)
     v = v_ref[0, 0].astype(jnp.float32)
-    if quantized:
-        k = k * ks_ref[0, 0][:, None]
-        v = v * vs_ref[0, 0][:, None]
     q = q_ref[0]                            # (group, d)
     s = jax.lax.dot_general(
         q.astype(jnp.float32), k,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale    # (group, page_size)
+    if quantized:
+        hk = b % ks_ref.shape[1]
+        s = s * ks_ref[0, pl.ds(hk, 1), :]             # (1, page_size)
     kpos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(kpos < len_ref[b], s, NEG_INF)
 
@@ -74,6 +73,8 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     p = jnp.exp(s - m_new[:, None])
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
+    if quantized:
+        p = p * vs_ref[0, pl.ds(hk, 1), :]
     acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
         p.astype(v.dtype), v,
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -100,9 +101,9 @@ def paged_flash_decode_pallas(q: jax.Array, k_pages: jax.Array,
     (B*Hkv, max_pages) physical ids (page 0 = trash, masked by length);
     lengths: (B*Hkv,) valid cached tokens (>= 1: page 0 of every live slot
     covers position 0, so the first grid step is never fully masked).
-    Scales (int8 pools): (Hkv, P, page_size) f32.  Returns (B*Hkv, group,
-    D).  The wrapper (kernels/ops.py) replicates per-slot tables/lengths
-    across kv heads so grid axis 0 is flat (b, kv head)."""
+    Scales (int8 pools): page-major (P, Hkv, page_size) f32.  Returns
+    (B*Hkv, group, D).  The wrapper (kernels/ops.py) replicates per-slot
+    tables/lengths across kv heads so grid axis 0 is flat (b, kv head)."""
     BH, G, Dh = q.shape
     Hkv, P, pg, _ = k_pages.shape
     assert pg == page_size, (pg, page_size)
@@ -120,9 +121,10 @@ def paged_flash_decode_pallas(q: jax.Array, k_pages: jax.Array,
             lambda h, j, pt_ref, len_ref: (h % Hkv, pt_ref[h, j], 0, 0))
 
     def scale_spec():
+        # every kv head's scales of the table's page (see module doc)
         return pl.BlockSpec(
-            (1, 1, page_size),
-            lambda h, j, pt_ref, len_ref: (h % Hkv, pt_ref[h, j], 0))
+            (1, Hkv, page_size),
+            lambda h, j, pt_ref, len_ref: (pt_ref[h, j], 0, 0))
 
     in_specs = [
         pl.BlockSpec((1, G, Dh), lambda h, j, pt_ref, len_ref: (h, 0, 0)),

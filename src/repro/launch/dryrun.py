@@ -1,13 +1,3 @@
-import os
-# merge, don't clobber: callers that already forced a device count
-# (benchmark workers, tests) keep theirs, callers with unrelated XLA_FLAGS
-# still get the 512-device forcing — jax only reads this at init.
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=512").strip()
-del _flags
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell: jax.jit(step, in_shardings).lower(*ShapeDtypeStructs)
@@ -22,6 +12,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -86,7 +77,7 @@ def run_cell(arch: str, shape: str, mesh_name: str, *, force: bool = False,
                                   donate_argnums=donate).lower(*args)
                 compiled = lowered.compile()
             mem = compat.memory_stats(compiled)
-            xla_cost = compat.cost_analysis(compiled)
+            xla_cost = compiled.cost_analysis()
             # scan-aware per-device costs (XLA's cost_analysis counts while
             # bodies once — see analysis/hlo_cost.py); x chips = global.
             hlo_txt = compiled.as_text()
@@ -168,7 +159,19 @@ def roofline_from_cell(cell: dict) -> RooflineReport | None:
         model_bytes=cell.get("model_bytes", 0.0))
 
 
+def _force_host_devices() -> None:
+    """Give the CPU backend 512 virtual devices for the pod meshes.
+    Merges, never clobbers: a caller that already forced a device count
+    keeps it.  JAX reads XLA_FLAGS when the backend starts, so this must
+    run before the first device query."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count=512").strip()
+
+
 def main() -> None:
+    _force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -2,6 +2,8 @@
 never touches jax device state."""
 from __future__ import annotations
 
+import os
+
 from repro.runtime import compat
 
 
@@ -16,6 +18,16 @@ def make_local_mesh():
     return compat.make_mesh((1, 1), ("data", "model"))
 
 
+def make_host_mesh():
+    """(1, n) mesh over every device of this host: the model axis spans
+    all n chips, so parameters shard tensor-parallel and long sequences
+    take the ring (``RingAttnPolicy``) across them."""
+    import jax
+    n = len(jax.local_devices())
+    return compat.make_mesh((1, n), ("data", "model"),
+                            devices=jax.local_devices())
+
+
 def make_worker_mesh():
     """1-device mesh over THIS process's first local device.
 
@@ -25,6 +37,25 @@ def make_worker_mesh():
     0's device.  Built from ``jax.local_devices()`` the mesh stays on the
     rank's own device whether or not the coordinator is up."""
     import jax
-    import numpy as np
-    dev = np.asarray(jax.local_devices()[:1]).reshape(1, 1)
-    return jax.sharding.Mesh(dev, ("data", "model"))
+    return compat.make_mesh((1, 1), ("data", "model"),
+                            devices=jax.local_devices()[:1])
+
+
+def refuse_gang_on_tpu(nprocs: int) -> None:
+    """Raise unless ``nprocs`` JAX worker processes can coexist here.
+
+    A chip belongs to one process at a time, and every JAX worker would
+    claim all of this host's TPU chips; the second would fail or hang.
+    Workers whose ``JAX_PLATFORMS`` list leaves out ``tpu`` (for example
+    ``cpu``) never touch the chips."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    listed = {p.strip().lower() for p in platforms.split(",") if p.strip()}
+    if nprocs <= 1 or (listed and "tpu" not in listed):
+        return
+    chips = compat.tpu_chips_attached()
+    if chips:
+        raise RuntimeError(
+            f"refusing to start {nprocs} JAX worker processes on a host "
+            f"with {chips} TPU chip(s): each worker would claim every "
+            "chip. Run one process per host (N=1), or set "
+            "JAX_PLATFORMS=cpu to run the workers on the CPU.")

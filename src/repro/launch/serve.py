@@ -4,11 +4,16 @@ bundle.
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --requests 6
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
         --kv-mode paged --page-size 16
+    # published widths (get_bundle(arch, smoke=False)), on the chip
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --full \
+        --kv-mode paged --prompt-len 256 --max-len 512 --max-new 32
 
 Fleet modes (the serving fleet of ``serving/fleet.py``):
 
     # N REAL serve worker processes under runtime/supervisor.py; a worker
-    # killed by --chaos die@T:host=H exits 43 and is restarted
+    # killed by --chaos die@T:host=H exits 43 and is restarted.  Each
+    # worker is a JAX process that claims every TPU chip of its host, so
+    # N > 1 is refused on a TPU host unless JAX_PLATFORMS=cpu.
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
         --kv-mode paged --fleet 2 --chaos die@4:host=1
 
@@ -38,6 +43,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_bundle
+from repro.launch.cache import enable_compile_cache
 from repro.serving import ServeConfig, ServingEngine
 
 
@@ -80,6 +86,14 @@ class _BundleAdapter:
                                       lengths, counts)
 
 
+def init_params(bundle, seed: int = 0):
+    """Seeded random weights, drawn under jit: each f32 draw fuses into
+    its bf16 cast, so a full-width model's init never holds a whole f32
+    weight beside the growing bf16 tree (qwen3-4b would not fit a 16 GB
+    chip eagerly)."""
+    return jax.jit(bundle.init_params)(jax.random.PRNGKey(seed))
+
+
 def build_engine(arch: str, *, smoke: bool = True, slots: int = 4,
                  max_len: int = 64, max_new: int = 8, kv_mode: str = "dense",
                  page_size: int = 16, num_pages: int | None = None,
@@ -96,7 +110,7 @@ def build_engine(arch: str, *, smoke: bool = True, slots: int = 4,
     degradation knobs (``max_admission_retries``, ``admission_backoff``,
     ``shed_pressure``, ``shed_patience``, ``shed_min_priority``)."""
     bundle = get_bundle(arch, smoke=smoke)
-    params = bundle.init_params(jax.random.PRNGKey(seed))
+    params = init_params(bundle, seed)
     extras = {}
     if bundle.kind == "audio":
         extras["frames"] = np.zeros(
@@ -130,7 +144,7 @@ def build_fleet(arch: str, n_hosts: int, *, smoke: bool = True,
     pagecorrupt)."""
     from repro.serving import FleetConfig, LocalFleet
     bundle = get_bundle(arch, smoke=smoke)
-    params = bundle.init_params(jax.random.PRNGKey(seed))
+    params = init_params(bundle, seed)
     adapter = _BundleAdapter(bundle, {})
     cfg = ServeConfig(batch=slots, max_len=max_len, max_new_tokens=max_new,
                       kv_mode=kv_mode, page_size=page_size,
@@ -172,8 +186,9 @@ def run_worker(a) -> None:
                          result_out=a.result_out)
     chaos = ChaosInjector(a.chaos or (), seed=a.seed)
     engine, vocab = build_engine(
-        a.arch, slots=a.slots, max_len=a.max_len, max_new=a.max_new,
-        kv_mode=a.kv_mode, page_size=a.page_size, seed=a.seed)
+        a.arch, smoke=a.smoke, slots=a.slots, max_len=a.max_len,
+        max_new=a.max_new, kv_mode=a.kv_mode, page_size=a.page_size,
+        seed=a.seed)
     prompts = fleet_trace(vocab, n_requests=a.requests,
                           prompt_len=a.prompt_len,
                           prefix_share=a.prefix_share, seed=a.seed)
@@ -204,8 +219,10 @@ def run_fleet_supervised(a) -> dict:
     slice; the parent merges the per-rank result JSONs."""
     import tempfile
 
+    from repro.launch.mesh import refuse_gang_on_tpu
     from repro.runtime.chaos import split_spec_strings
     from repro.runtime.supervisor import RestartPolicy, Supervisor
+    refuse_gang_on_tpu(a.fleet)
     fleet_dir = a.fleet_dir or tempfile.mkdtemp(prefix="serve_fleet_")
     results_dir = os.path.join(fleet_dir, "results")
     os.makedirs(results_dir, exist_ok=True)
@@ -227,6 +244,7 @@ def run_fleet_supervised(a) -> dict:
                 "--max-len", str(a.max_len),
                 "--max-new", str(a.max_new),
                 "--seed", str(a.seed),
+                *([] if a.smoke else ["--full"]),
                 "--result-out",
                 os.path.join(results_dir, f"rank_{spec.tag}.json")]
         if spec.with_chaos:
@@ -321,6 +339,9 @@ def run(arch: str, *, smoke: bool = True, n_requests: int = 6,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="published widths instead of the smoke bundle")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=8)
@@ -372,13 +393,16 @@ def main():
     a = ap.parse_args()
     if a.tag is None:
         a.tag = a.process_id
+    enable_compile_cache()
     if a.worker:
         run_worker(a)
         return
     if a.fleet > 1:
         run_fleet_supervised(a)
         return
-    results = run(a.arch, n_requests=a.requests, slots=a.slots,
+    results = run(a.arch, smoke=a.smoke, n_requests=a.requests,
+                  slots=a.slots, prompt_len=a.prompt_len,
+                  max_len=a.max_len, seed=a.seed,
                   max_new=a.max_new, kv_mode=a.kv_mode,
                   page_size=a.page_size, num_pages=a.num_pages,
                   prefix_cache=a.prefix_cache, prefix_share=a.prefix_share,

@@ -15,8 +15,11 @@ global failure budget is blown.  See ``repro.runtime.supervisor`` for
 the policy machine and ``docs/ARCHITECTURE.md`` ("Fleet runtime") for
 the state diagram.
 
-This process never imports jax on its supervision path (workers do); the
-optional final checkpoint audit is the one lazy exception.
+This process never starts a JAX backend (workers do), so it never holds
+a chip; the optional final checkpoint audit is the one lazy exception.
+On a TPU host it refuses ``--nprocs`` > 1 unless ``JAX_PLATFORMS=cpu``
+(see :func:`repro.launch.mesh.refuse_gang_on_tpu`): every worker would
+claim all of the host's chips.
 """
 from __future__ import annotations
 
@@ -26,6 +29,7 @@ import os
 import sys
 import tempfile
 
+from repro.launch.mesh import refuse_gang_on_tpu
 from repro.runtime.chaos import split_spec_strings
 from repro.runtime.fleet import allocate_ports
 from repro.runtime.supervisor import (LaunchSpec, RestartPolicy, Supervisor,
@@ -110,6 +114,7 @@ def main(argv=None) -> int:
     ap.add_argument("--hang-timeout-s", type=float, default=30.0)
     a = ap.parse_args(argv)
 
+    refuse_gang_on_tpu(a.nprocs)
     fleet_dir = a.fleet_dir or tempfile.mkdtemp(prefix="repro-fleet-")
     os.makedirs(fleet_dir, exist_ok=True)
     _, worker_chaos = split_spec_strings(a.chaos)

@@ -65,8 +65,9 @@ import repro.obs as obs
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_bundle
 from repro.data import DataConfig, make_train_iterator
-from repro.launch.mesh import (make_local_mesh, make_production_mesh,
-                               make_worker_mesh)
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import (make_host_mesh, make_local_mesh,
+                               make_production_mesh, make_worker_mesh)
 from repro.optim import AdamWConfig, adamw_init
 from repro.parallel.sharding import param_specs
 from repro.runtime import (ChaosInjector, ChaosKilled, FleetWorker,
@@ -87,7 +88,11 @@ def run(arch: str, *, smoke: bool = True, steps: int = 20,
         max_recoveries: int = 8, trace_out: str | None = None,
         metrics_out: str | None = None, telemetry=None,
         fleet: FleetWorker | None = None,
-        total_steps: int | None = None) -> dict:
+        total_steps: int | None = None,
+        n_layers: int | None = None) -> dict:
+    """Train ``arch`` for ``steps`` steps under the recovery state machine
+    (module docstring).  ``n_layers`` cuts the depth, widths unchanged,
+    to what the mesh holds."""
     if chaos is not None and not isinstance(chaos, ChaosInjector):
         chaos = ChaosInjector(chaos, seed=chaos_seed)
     if fleet is not None and fleet.distributed == "jax" and fleet.coordinator:
@@ -95,10 +100,14 @@ def run(arch: str, *, smoke: bool = True, steps: int = 20,
         fleet.dist_ok = compat.distributed_initialize(
             fleet.coordinator, fleet.num_processes, fleet.process_id)
     bundle = get_bundle(arch, smoke=smoke)
+    if n_layers is not None:
+        bundle = dataclasses.replace(
+            bundle, cfg=dataclasses.replace(bundle.cfg, n_layers=n_layers))
     if fleet is not None:
         mesh = make_worker_mesh()
     else:
         mesh = {"local": make_local_mesh,
+                "host": make_host_mesh,
                 "single": make_production_mesh,
                 "multi": lambda: make_production_mesh(multi_pod=True)
                 }[mesh_kind]()
@@ -109,24 +118,22 @@ def run(arch: str, *, smoke: bool = True, steps: int = 20,
     n_devices = jax.device_count()
     n_procs = jax.process_count()
 
-    key = jax.random.PRNGKey(0)
-    params = bundle.init_params(key)
-    opt = adamw_init(params)
-
-    pspecs = param_specs(bundle.kind, params, mesh)
+    pspecs = param_specs(bundle.kind, bundle.abstract_params(), mesh)
     psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
                        is_leaf=lambda x: isinstance(x, P))
     tree_sh = {"params": psh,
                "opt": {"mu": psh, "nu": psh,
                        "step": NamedSharding(mesh, P())}}
+    # built in place on the mesh: an eager init would hold the whole f32
+    # draw and both f32 Adam moments on one device first
+    params = jax.jit(bundle.init_params, out_shardings=psh)(
+        jax.random.PRNGKey(0))
+    opt = jax.jit(adamw_init, out_shardings=tree_sh["opt"])(params)
 
     def sharding_fn(tree):
         """Elastic re-shard: place a restored host tree onto whatever mesh
         this process currently drives."""
         return jax.device_put(tree, tree_sh)
-
-    params = jax.device_put(params, psh)
-    opt = jax.device_put(opt, tree_sh["opt"])
 
     vocab = getattr(bundle.cfg, "vocab")
     data_cfg = DataConfig(vocab=vocab, seq_len=seq_len,
@@ -217,7 +224,7 @@ def run(arch: str, *, smoke: bool = True, steps: int = 20,
                              start_step=start_step)
     extras = make_extras(global_batch // n_data_hosts)
 
-    history, step_log, events = [], [], []
+    history, grad_norms, step_log, events = [], [], [], []
     i = start_step
     recoveries = 0
     last_saved = start_step if mgr else None
@@ -336,6 +343,7 @@ def run(arch: str, *, smoke: bool = True, steps: int = 20,
                     tel.metrics.observe("train_step_s", dt)
 
                 history.append(loss)
+                grad_norms.append(float(metrics["grad_norm"]))
                 step_log.append(i)
                 if i % log_every == 0:
                     flag = "" if finite else "  [nonfinite->skipped]"
@@ -447,7 +455,8 @@ def run(arch: str, *, smoke: bool = True, steps: int = 20,
             "device_count": n_devices,
             "process_count": n_procs,
         })
-    return {"losses": history, "steps": step_log, "events": events,
+    return {"losses": history, "grad_norms": grad_norms, "steps": step_log,
+            "events": events,
             "params": params, "opt": opt,
             "telemetry": tel.snapshot() if tel.enabled else None}
 
@@ -461,7 +470,10 @@ def main():
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--mesh", default="local",
-                    choices=["local", "single", "multi"])
+                    choices=["local", "host", "single", "multi"],
+                    help="local: one device; host: (1, n) over this "
+                         "host's n devices; single/multi: the 16x16 "
+                         "production pod meshes")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--microbatches", type=int, default=1)
@@ -504,6 +516,7 @@ def main():
                     help="global step horizon (restart-safe endpoint); "
                          "overrides --steps counting from the restore")
     a = ap.parse_args()
+    enable_compile_cache()
     fleet = None
     if a.num_processes is not None:
         ports = tuple(int(p) for p in a.stripe_ports.split(",")) \

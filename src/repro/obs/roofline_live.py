@@ -163,7 +163,7 @@ def observe_compiled(fn, *args) -> dict[str, float]:
     from repro.runtime import compat
 
     compiled = jax.jit(fn).lower(*args).compile()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     mem = compat.memory_stats(compiled)
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),

@@ -27,7 +27,7 @@ Backward (a second ring pass with the same hop schedule):
 
 Masking (causal / sliding-window) and GQA grouping are handled here so
 callers (``models/layers.attention``) only pick a policy; the varying-
-manual-axes typing required on jax >= 0.6 goes through ``compat.pcast`` /
+manual-axes typing inside shard_map goes through ``compat.pcast`` /
 ``compat.match_vma`` like every other shard_map body in the repo.
 """
 from __future__ import annotations
@@ -335,12 +335,26 @@ def _qkv_spec(spec: _RingSpec):
     return P(spec.dspec, spec.axis, None, None)
 
 
+def _shard(spec: _RingSpec, body, in_specs, out_specs):
+    # JAX 0.9.0 limitation: the Pallas HLO interpreter (the fused fold
+    # off-TPU; jax/_src/pallas/hlo_interpreter.py, pallas_call_hlo_interpret)
+    # evaluates the kernel body with varying blocks but unvarying grid
+    # indices, and `dynamic_slice`'s vma rule raises "Primitive
+    # dynamic_slice requires varying manual axes to match" (the error's
+    # own advice: report upstream, pass check_vma=False).  Drop this once
+    # an upgraded interpreter passes test_ring_fused_pallas_hop_matches_
+    # einsum with the check on.  Compiled Mosaic kernels keep the check
+    # (their out-shapes carry the vma), guarded by
+    # test_ring_fused_hop_compiles_on_2x2.
+    kw = {"check_vma": False} if spec.fused and spec.interpret else {}
+    return compat.shard_map(functools.partial(body, spec), mesh=spec.mesh,
+                            in_specs=in_specs, out_specs=out_specs, **kw)
+
+
 def _shard_fwd(spec: _RingSpec, q, k, v):
     qs = _qkv_spec(spec)
-    fn = compat.shard_map(
-        functools.partial(_fwd_body, spec), mesh=spec.mesh,
-        in_specs=(qs, qs, qs),
-        out_specs=(qs, P(spec.dspec, None, None, spec.axis)))
+    fn = _shard(spec, _fwd_body, (qs, qs, qs),
+                (qs, P(spec.dspec, None, None, spec.axis)))
     return fn(q, k, v)
 
 
@@ -358,10 +372,9 @@ def _ring_attn_fwd(spec: _RingSpec, q, k, v):
 def _ring_attn_bwd(spec: _RingSpec, res, do):
     q, k, v, o, lse = res
     qs = _qkv_spec(spec)
-    fn = compat.shard_map(
-        functools.partial(_bwd_body, spec), mesh=spec.mesh,
-        in_specs=(qs, qs, qs, qs, P(spec.dspec, None, None, spec.axis), qs),
-        out_specs=(qs, qs, qs))
+    fn = _shard(spec, _bwd_body,
+                (qs, qs, qs, qs, P(spec.dspec, None, None, spec.axis), qs),
+                (qs, qs, qs))
     return fn(q, k, v, o, lse, do)
 
 
@@ -435,4 +448,7 @@ def ring_attention(q, k, v, *, causal=True, window=None, mesh=None,
     if impl != "vjp":
         raise ValueError(f"ring_attention impl {impl!r} not in "
                          "('vjp', 'naive')")
+    from repro.kernels.ops import _record_dispatch
+    _record_dispatch("ring_attention", impl="pallas" if use_fused else "xla",
+                     ring=m, s=S, block_q=bq, block_k=bk)
     return _ring_attn(spec, q, k, v)

@@ -1,247 +1,78 @@
-"""Version-adaptive JAX portability layer.
+"""JAX portability layer, written against the installed JAX (0.9.0).
 
-Every symbol that drifted across the JAX releases this repo supports is
-resolved ONCE here, at import time, and the rest of the codebase imports
-from this module instead of touching the drifting API directly.  The
-supported range is jax 0.4.37 (the pinned container baseline) through the
-current ≥ 0.6/0.7 API family; each shim prefers the NEW spelling when it
-exists and falls back to an equivalent on older releases, so the same
-source runs unmodified on both ends of the range.
+The rest of the code base takes its mesh, ``shard_map``, varying-axes
+typing and Pallas TPU helpers from here, and ``tests/test_compat.py``
+greps that no other module spells them directly.  A JAX upgrade that
+moves one of these symbols is then repaired in this file alone.
 
-Shim inventory (new spelling -> introduced -> old fallback):
+Two choices here are not the installed JAX's defaults:
 
-``make_mesh(axis_shapes, axis_names)``
-    ``jax.make_mesh`` (added 0.4.35).  Fallback: build the device array
-    with ``jax.experimental.mesh_utils.create_device_mesh`` and wrap it
-    in ``jax.sharding.Mesh`` — identical semantics, no device reordering
-    heuristics beyond what mesh_utils already applies.
+* :func:`make_mesh` builds meshes with ``AxisType.Auto`` axes.  Since
+  0.7 ``jax.make_mesh`` defaults to Explicit axes, under which a gather
+  from a vocab-sharded embedding table or a scatter into a sharded page
+  pool raises ``ShardingTypeError`` and ``PartitionSpec.UNCONSTRAINED``
+  is refused.  Every sharding in this repo is written for GSPMD
+  propagation (Auto).
+* :func:`tpu_compiler_params` raises on a keyword the installed
+  ``pltpu.CompilerParams`` does not take: kernel compiler parameters
+  never vanish silently.
 
-``set_mesh(mesh)``
-    Ambient-mesh context manager.  Prefers ``jax.set_mesh`` (promoted to
-    the top level around 0.7, usable as a context manager), then
-    ``jax.sharding.use_mesh`` (the experimental spelling added ~0.5.x).
-    Fallback (0.4.x): a ``contextmanager`` that (a) records the concrete
-    mesh in a module thread-local so :func:`get_abstract_mesh` can see it
-    and (b) enters the legacy ``with mesh:`` resource env, which is what
-    makes ``jax.lax.with_sharding_constraint(x, PartitionSpec(...))``
-    accept bare PartitionSpecs on 0.4.x (outside a resource env that call
-    raises ``RuntimeError: ... requires a non-empty mesh``).
-
-``get_abstract_mesh()``
-    ``jax.sharding.get_abstract_mesh`` (added ~0.5.0; returns an
-    ``AbstractMesh``, empty when no ambient mesh is set).  Fallback: the
-    thread-local *concrete* Mesh recorded by :func:`set_mesh`, or ``None``
-    when no mesh context is active.  Callers therefore must treat "no
-    mesh" as ``mesh is None or getattr(mesh, "empty", False)`` — both
-    representations satisfy that test, and a concrete Mesh supports the
-    same ``axis_names`` / ``shape`` lookups the call sites use.
-
-``shard_map(f, *, mesh, in_specs, out_specs, ...)``
-    ``jax.shard_map`` (public at the top level since ~0.6).  Fallback:
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep=False`` —
-    0.4.x's replication checker predates the vma/pvary typing the new
-    call sites rely on (they mark carries varying via :func:`pcast`,
-    which is an identity on 0.4.x), so the old checker would reject
-    otherwise-correct programs.  Disabling it trades away a static check
-    and some transpose efficiency, never numerics.
-
-``pcast(x, axes, to="varying")`` / ``vma(x)`` / ``match_vma(x, like)``
-    The varying-manual-axes type system: ``jax.lax.pcast`` (0.7; 0.6
-    spelled the varying direction ``jax.lax.pvary``) and
-    ``jax.typeof(x).vma`` (0.6).  Fallback: ``pcast`` is the identity and
-    ``vma`` returns ``frozenset()`` — on 0.4.x (with ``check_rep=False``)
-    nothing tracks replication, so "already matches" is the correct
-    degenerate answer.  ``match_vma(x, like)`` is the common idiom
-    (promote ``x`` to carry every varying axis ``like`` has) packaged so
-    call sites don't reimplement the set arithmetic.
-
-``Element(n)`` / ``element_block_spec(block_shape, index_map)``
-    Per-dimension element-indexed Pallas blocks: ``pl.Element`` (added
-    with the BlockSpec indexing rework, ~0.6; the same rework REMOVED the
-    0.4.x ``indexing_mode=`` argument, so the two spellings are mutually
-    exclusive).  ``Element`` here is always this module's int-subclass
-    marker; :func:`element_block_spec` translates it per version:
-
-    * new JAX: marker dims become real ``pl.Element(n)`` dims and the
-      user index map passes through untouched (element offsets for
-      Element dims, block indices for Blocked dims);
-    * 0.4.x: the whole spec is lowered to ``indexing_mode=pl.Unblocked()``
-      (element offsets for EVERY dim) and the index map is wrapped to
-      rescale the Blocked dims' block indices by their block sizes.
-      Semantics are identical; only the index arithmetic moves.
-
-``prefetch_scalar_grid_spec(...)``
-    TPU scalar-prefetch grid spec (index maps may read prefetched scalar
-    refs — how the paged-attention kernel chases its page table).  The
-    class has lived at ``pltpu.PrefetchScalarGridSpec`` across the whole
-    supported range but is resolved lazily here (no eager
-    ``pallas.tpu`` import for sim-only entry points) and probed at both
-    its TPU-module and core-pallas homes so a future relocation lands in
-    one place.
-
-``tpu_compiler_params(**kwargs)``
-    ``pltpu.CompilerParams`` (renamed ~0.6/0.7) vs ``TPUCompilerParams``
-    (0.4.x–0.5.x).  Returns a ``{"compiler_params": ...}`` kwargs dict
-    ready to splat into ``pl.pallas_call``, or ``{}`` when neither class
-    exists or the signature rejects the request (signature drift) — the
-    params are a performance hint, so dropping them is always safe.
-
-``cost_analysis(compiled)``
-    ``Compiled.cost_analysis()`` returns a per-module ``dict`` on ≥ 0.5
-    but a one-element ``list`` of dicts on 0.4.x.  This wrapper always
-    returns the flat dict (``{}`` for an empty list).
-
-``memory_stats(compiled)``
-    Normalized ``Compiled.memory_analysis()`` byte counts.  The analysis
-    object's availability and attribute spellings vary by backend and
-    release (some backends return ``None``, some raise, TPU adds fields
-    CPU lacks), so this wrapper always returns the same four-key dict
-    with zeros for anything missing — callers treat it as best-effort
-    telemetry (dry-run tables, ring benchmarks, residual-size tests).
-
-``tree_map`` / ``tree_leaves`` / ``tree_flatten`` / ``tree_unflatten``
-    ``jax.tree.*`` (added 0.4.25, the preferred spelling; the historical
-    ``jax.tree_map`` aliases were deleted in 0.6).  Fallback:
-    ``jax.tree_util.tree_*``, which exist everywhere.
-
-``random_key(seed)``
-    Typed PRNG keys: ``jax.random.key`` (0.4.16).  Fallback:
-    ``jax.random.PRNGKey`` (raw uint32 keys).  Both feed every
-    ``jax.random`` sampler in the supported range.
-
-``distributed_initialize(coordinator, num_processes, process_id)``
-    Multi-process runtime bring-up (``jax.distributed.initialize``).
-    The core three keywords are stable across the supported range, but
-    the surrounding signature drifts (0.6 added
-    ``cluster_detection_method``; ``initialization_timeout`` moved) — so
-    the call is filtered against the live signature and failure is a
-    WARNED ``False``, never an exception: a fleet worker whose
-    distributed runtime cannot come up still runs its local replica, it
-    just reports ``dist_ok=False``.  ``distributed_shutdown()`` is the
-    matching best-effort teardown.
-
-Import-order note: the Pallas shims resolve ``jax.experimental.pallas``
-lazily on first use (cached thereafter), so sim/benchmark entry points
-that never touch a kernel don't pay the Pallas import; nothing in this
-module touches device state, so importing it cannot pin a backend.
+Pallas is imported lazily, so entry points that never touch a kernel do
+not pay for it.  Nothing here touches device state, so importing this
+module cannot pin a backend.
 """
 from __future__ import annotations
 
-import contextlib
-import inspect
-import threading
 import warnings
 from typing import Any, Callable, Sequence
 
 import jax
 
 __all__ = [
-    "JAX_VERSION",
     "make_mesh", "set_mesh", "get_abstract_mesh", "shard_map",
     "pcast", "vma", "match_vma",
     "Element", "element_block_spec", "prefetch_scalar_grid_spec",
-    "tpu_compiler_params",
-    "cost_analysis", "memory_stats",
-    "tree_map", "tree_leaves", "tree_flatten", "tree_unflatten",
-    "random_key",
+    "tpu_compiler_params", "memory_stats", "tpu_chips_attached",
     "distributed_initialize", "distributed_shutdown",
 ]
 
-JAX_VERSION: tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit())
-
 
 # ---------------------------------------------------------------------------
-# Mesh construction
+# Meshes and the ambient-mesh context
 # ---------------------------------------------------------------------------
 
-if hasattr(jax, "make_mesh"):
-    make_mesh = jax.make_mesh
-else:  # pragma: no cover - exercised only on jax < 0.4.35
-    def make_mesh(axis_shapes: Sequence[int],
-                  axis_names: Sequence[str]) -> jax.sharding.Mesh:
-        from jax.experimental import mesh_utils
-        devices = mesh_utils.create_device_mesh(tuple(axis_shapes))
-        return jax.sharding.Mesh(devices, tuple(axis_names))
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
+              devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` (see above).
+    ``devices`` defaults to ``jax.devices()``."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=auto, devices=devices)
 
 
-# ---------------------------------------------------------------------------
-# Ambient mesh context: set_mesh / get_abstract_mesh
-# ---------------------------------------------------------------------------
-
-_tls = threading.local()
-
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-elif hasattr(jax.sharding, "use_mesh"):
-    set_mesh = jax.sharding.use_mesh
-else:
-    @contextlib.contextmanager
-    def set_mesh(mesh: jax.sharding.Mesh):
-        prev = getattr(_tls, "mesh", None)
-        _tls.mesh = mesh
-        try:
-            # legacy resource env: lets with_sharding_constraint resolve
-            # bare PartitionSpecs against `mesh` while tracing inside.
-            with mesh:
-                yield mesh
-        finally:
-            _tls.mesh = prev
-
-
-if hasattr(jax.sharding, "get_abstract_mesh"):
-    get_abstract_mesh = jax.sharding.get_abstract_mesh
-else:
-    def get_abstract_mesh():
-        return getattr(_tls, "mesh", None)
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_experimental
-
-    def shard_map(f: Callable, *, mesh=None, in_specs, out_specs, **kwargs):
-        kwargs.setdefault("check_rep", False)
-        return _shard_map_experimental(f, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs, **kwargs)
+set_mesh = jax.set_mesh
+get_abstract_mesh = jax.sharding.get_abstract_mesh
+shard_map = jax.shard_map
 
 
 # ---------------------------------------------------------------------------
 # Varying-manual-axes (vma) typing: pcast / vma / match_vma
 # ---------------------------------------------------------------------------
 
-if hasattr(jax.lax, "pcast"):
-    pcast = jax.lax.pcast
-elif hasattr(jax.lax, "pvary"):
-    def pcast(x, axes, to: str = "varying"):
-        if to != "varying":
-            raise NotImplementedError(
-                f"pcast(to={to!r}) has no jax-0.6 equivalent shimmed here")
-        return jax.lax.pvary(x, axes)
-else:
-    def pcast(x, axes, to: str = "varying"):
-        return x
+pcast = jax.lax.pcast
 
 
 def vma(x) -> frozenset:
-    """The varying manual axes of ``x``'s type; empty pre-0.6 (untracked)."""
-    try:
-        return frozenset(jax.typeof(x).vma)
-    except AttributeError:
-        return frozenset()
+    """The varying manual axes of ``x``'s type (empty outside shard_map)."""
+    return frozenset(jax.typeof(x).vma)
 
 
 def match_vma(x, like):
     """Promote ``x`` to carry every varying axis ``like`` carries.
 
-    Inside shard_map on ≥ 0.6, scan/loop carries must be typed with the
-    same varying axes as the values they combine with; pre-0.6 this is a
-    no-op because nothing is tracked."""
+    Inside shard_map, scan/loop carries and ``pallas_call`` out-shapes
+    must be typed with the same varying axes as the values they combine
+    with."""
     want = vma(like) - vma(x)
     if want:
         x = pcast(x, tuple(want), to="varying")
@@ -252,19 +83,6 @@ def match_vma(x, like):
 # Pallas: element-indexed BlockSpecs
 # ---------------------------------------------------------------------------
 
-_pallas_mod = None
-
-
-def _pallas():
-    """Lazy, cached ``jax.experimental.pallas`` — kernels are the only
-    consumers, so pure-sim entry points never pay this import."""
-    global _pallas_mod
-    if _pallas_mod is None:
-        from jax.experimental import pallas
-        _pallas_mod = pallas
-    return _pallas_mod
-
-
 class Element(int):
     """Marker for a block dim whose index-map output is an ELEMENT offset
     (halo/overlapping windows), not a block index.  Use only inside
@@ -274,28 +92,16 @@ class Element(int):
 def element_block_spec(block_shape: Sequence[int],
                        index_map: Callable[..., tuple]):
     """BlockSpec mixing :class:`Element` (element-indexed) and plain int
-    (block-indexed) dims.  ``index_map`` follows the NEW JAX convention:
-    element offsets for Element dims, block indices for the rest."""
-    pl = _pallas()
-    pl_element = getattr(pl, "Element", None)
-    if pl_element is not None:
-        shape = tuple(pl_element(int(d)) if isinstance(d, Element) else d
-                      for d in block_shape)
-        return pl.BlockSpec(shape, index_map)
-    sizes = tuple(int(d) for d in block_shape)
-    is_element = tuple(isinstance(d, Element) for d in block_shape)
-
-    def as_element_offsets(*grid_idx):
-        idx = index_map(*grid_idx)
-        return tuple(i if e else i * s
-                     for i, e, s in zip(idx, is_element, sizes))
-
-    return pl.BlockSpec(sizes, as_element_offsets,
-                        indexing_mode=pl.Unblocked())
+    (block-indexed) dims.  ``index_map`` returns element offsets for
+    Element dims and block indices for the rest."""
+    from jax.experimental import pallas as pl
+    shape = tuple(pl.Element(int(d)) if isinstance(d, Element) else d
+                  for d in block_shape)
+    return pl.BlockSpec(shape, index_map)
 
 
 # ---------------------------------------------------------------------------
-# Pallas: scalar-prefetch grid specs
+# Pallas: scalar-prefetch grid specs and compiler params
 # ---------------------------------------------------------------------------
 
 def prefetch_scalar_grid_spec(*, num_scalar_prefetch: int, grid,
@@ -304,51 +110,24 @@ def prefetch_scalar_grid_spec(*, num_scalar_prefetch: int, grid,
     arrays prefetched before the kernel runs and passed to every index map
     (trailing arguments) and to the kernel body (leading refs).  This is
     the mechanism behind page-table indirection in the paged-attention
-    kernel.  Resolved lazily; probed in both ``pallas.tpu`` and core
-    ``pallas`` so a relocation upstream is a one-line fix here."""
+    kernel and the pair-table schedule of the flash kernels."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = (getattr(pltpu, "PrefetchScalarGridSpec", None)
-           or getattr(_pallas(), "PrefetchScalarGridSpec", None))
-    if cls is None:  # pragma: no cover - no release in range lacks it
-        raise NotImplementedError(
-            "PrefetchScalarGridSpec not found in this JAX; the paged "
-            "attention kernel needs scalar prefetch")
-    return cls(num_scalar_prefetch=num_scalar_prefetch, grid=grid,
-               in_specs=in_specs, out_specs=out_specs,
-               scratch_shapes=scratch_shapes)
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch, grid=grid,
+        in_specs=in_specs, out_specs=out_specs,
+        scratch_shapes=scratch_shapes)
 
-
-# ---------------------------------------------------------------------------
-# Pallas: TPU compiler params
-# ---------------------------------------------------------------------------
 
 def tpu_compiler_params(**kwargs) -> dict[str, Any]:
-    """``{"compiler_params": <params>}`` to splat into ``pl.pallas_call``,
-    or ``{}`` when the class is missing or its signature rejects ``kwargs``
-    (params are a scheduling hint — dropping them is always safe)."""
+    """``{"compiler_params": pltpu.CompilerParams(**kwargs)}`` to splat
+    into ``pl.pallas_call``.  An unknown keyword raises ``TypeError``."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = (getattr(pltpu, "CompilerParams", None)
-           or getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        return {}
-    try:
-        return {"compiler_params": cls(**kwargs)}
-    except TypeError:
-        return {}
+    return {"compiler_params": pltpu.CompilerParams(**kwargs)}
 
 
 # ---------------------------------------------------------------------------
 # Compiled-artifact introspection
 # ---------------------------------------------------------------------------
-
-def cost_analysis(compiled) -> dict[str, float]:
-    """Flat cost dict from a ``Compiled`` object (0.4.x returns a
-    one-element list of dicts; ≥ 0.5 returns the dict directly)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
 
 def memory_stats(compiled) -> dict[str, int]:
     """Normalized ``Compiled.memory_analysis()`` numbers, in bytes.
@@ -365,16 +144,21 @@ def memory_stats(compiled) -> dict[str, int]:
         mem = None
 
     def _get(name: str) -> int:
-        try:
-            return int(getattr(mem, name, 0) or 0)
-        except Exception:  # noqa: BLE001 — non-numeric drift
-            return 0
+        return int(getattr(mem, name, 0) or 0)
 
     arg = _get("argument_size_in_bytes")
     out = _get("output_size_in_bytes")
     tmp = _get("temp_size_in_bytes")
     return {"argument_bytes": arg, "output_bytes": out,
             "temp_bytes": tmp, "peak_bytes": arg + tmp}
+
+
+def tpu_chips_attached() -> int:
+    """TPU chips on this host's PCI bus, found without initializing a
+    backend — so a parent process can ask before it starts JAX children
+    (a chip belongs to one process at a time)."""
+    from jax._src import hardware_utils
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
 
 
 # ---------------------------------------------------------------------------
@@ -387,32 +171,19 @@ def distributed_initialize(coordinator_address: str, num_processes: int,
                            **extra) -> bool:
     """Bring up the multi-process runtime; ``True`` iff peers are joined.
 
-    Filters the request against the live ``jax.distributed.initialize``
-    signature (keywords around the stable core drift across 0.4.x/0.6.x)
-    and degrades to a warned ``False`` on any failure — callers treat the
+    Degrades to a warned ``False`` on any failure — callers treat the
     distributed runtime as an upgrade, not a requirement.  A second call
-    in an already-initialized process returns ``True``.
+    in an already-initialized process returns ``True``.  The coordinator
+    address, process count and id are always passed explicitly, so JAX
+    never looks for a cluster metadata service.
     """
-    dist = getattr(jax, "distributed", None)
-    init = getattr(dist, "initialize", None)
-    if init is None:  # pragma: no cover - every release in range has it
-        warnings.warn("jax.distributed.initialize not found; running "
-                      "without a distributed runtime", RuntimeWarning)
-        return False
     kwargs: dict[str, Any] = {"coordinator_address": coordinator_address,
                               "num_processes": int(num_processes),
                               "process_id": int(process_id), **extra}
     if timeout_s is not None:
         kwargs["initialization_timeout"] = int(timeout_s)
     try:
-        params = inspect.signature(init).parameters
-        if not any(p.kind is inspect.Parameter.VAR_KEYWORD
-                   for p in params.values()):
-            kwargs = {k: v for k, v in kwargs.items() if k in params}
-    except (TypeError, ValueError):  # pragma: no cover - C-level signature
-        pass
-    try:
-        init(**kwargs)
+        jax.distributed.initialize(**kwargs)
         return True
     except Exception as e:  # noqa: BLE001 — availability probe by contract
         if "already" in str(e).lower():
@@ -429,22 +200,3 @@ def distributed_shutdown() -> None:
         jax.distributed.shutdown()
     except Exception:  # noqa: BLE001 — teardown must never mask exit status
         pass
-
-
-# ---------------------------------------------------------------------------
-# Tree / random aliases
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "tree") and hasattr(jax.tree, "map"):
-    tree_map = jax.tree.map
-    tree_leaves = jax.tree.leaves
-    tree_flatten = jax.tree.flatten
-    tree_unflatten = jax.tree.unflatten
-else:  # pragma: no cover - exercised only on jax < 0.4.25
-    from jax import tree_util as _tree_util
-    tree_map = _tree_util.tree_map
-    tree_leaves = _tree_util.tree_leaves
-    tree_flatten = _tree_util.tree_flatten
-    tree_unflatten = _tree_util.tree_unflatten
-
-random_key = getattr(jax.random, "key", None) or jax.random.PRNGKey

@@ -1,8 +1,7 @@
 """Unit tests for the JAX portability layer (``repro.runtime.compat``).
 
-Every shim is exercised against whatever JAX is installed — on 0.4.x these
-hit the fallback paths, on ≥ 0.6 the native ones — so a rot in either
-branch surfaces as a failure here before it takes down the model zoo.
+Every helper is exercised against the installed JAX, so an upgrade that
+moves one of the spellings fails here before it takes down the model zoo.
 """
 import importlib
 import pkgutil
@@ -18,6 +17,13 @@ from repro.runtime import compat
 # ---------------------------------------------------------------------------
 # Mesh context: set/get round-trip
 # ---------------------------------------------------------------------------
+
+def test_make_mesh_axes_are_auto():
+    """Explicit axes (JAX's make_mesh default since 0.7) reject the
+    vocab-sharded embedding gather; compat meshes must be Auto."""
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    assert all(t == jax.sharding.AxisType.Auto for t in mesh.axis_types)
+
 
 def test_mesh_context_round_trip():
     mesh = compat.make_mesh((1, 1), ("data", "model"))
@@ -42,15 +48,17 @@ def test_mesh_context_nests():
 
 
 def test_sharding_constraint_resolves_under_set_mesh():
-    """Bare-PartitionSpec with_sharding_constraint must trace inside the
-    compat mesh context on every supported JAX (the 0.4.x resource-env
-    fallback is exactly what makes this legal there)."""
+    """Bare-PartitionSpec with_sharding_constraint (including
+    UNCONSTRAINED dims, which Explicit axes refuse) must trace inside the
+    compat mesh context."""
     from jax.sharding import PartitionSpec as P
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     x = jnp.ones((4, 8))
     with compat.set_mesh(mesh):
         y = jax.jit(lambda x: jax.lax.with_sharding_constraint(
             x, P("data", "model")))(x)
+        y = jax.jit(lambda x: jax.lax.with_sharding_constraint(
+            x, P(P.UNCONSTRAINED, "model")))(y)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
 
 
@@ -126,18 +134,20 @@ def test_element_marker_is_int():
 # ---------------------------------------------------------------------------
 
 def test_compiler_params_resolution():
+    from jax.experimental.pallas import tpu as pltpu
     kw = compat.tpu_compiler_params(
         dimension_semantics=("parallel", "arbitrary"))
-    # either the installed Pallas knows the class (kwargs dict ready to
-    # splat) or the shim degrades to {} — both must be pallas_call-safe.
-    assert isinstance(kw, dict)
-    assert set(kw) <= {"compiler_params"}
-    if kw:
-        assert kw["compiler_params"] is not None
+    assert set(kw) == {"compiler_params"}
+    assert isinstance(kw["compiler_params"], pltpu.CompilerParams)
+    assert kw["compiler_params"].dimension_semantics == (
+        "parallel", "arbitrary")
 
 
 def test_compiler_params_unknown_kwarg_degrades():
-    assert compat.tpu_compiler_params(definitely_not_a_real_kwarg=1) == {}
+    """A keyword the installed CompilerParams does not take is an error,
+    never a silent drop of the kernel's compiler params."""
+    with pytest.raises(TypeError):
+        compat.tpu_compiler_params(definitely_not_a_real_kwarg=1)
 
 
 # ---------------------------------------------------------------------------
@@ -166,39 +176,6 @@ def test_prefetch_scalar_grid_spec_gathers_by_table():
         interpret=True)(table, x)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(x[np.asarray(table)]))
-
-
-# ---------------------------------------------------------------------------
-# cost_analysis normalization
-# ---------------------------------------------------------------------------
-
-def test_cost_analysis_returns_flat_dict():
-    comp = jax.jit(lambda a, b: a @ b).lower(
-        jax.ShapeDtypeStruct((64, 64), jnp.float32),
-        jax.ShapeDtypeStruct((64, 64), jnp.float32)).compile()
-    cost = compat.cost_analysis(comp)
-    assert isinstance(cost, dict)
-    assert cost.get("flops", 0) > 0
-
-
-# ---------------------------------------------------------------------------
-# tree / random aliases
-# ---------------------------------------------------------------------------
-
-def test_tree_aliases():
-    tree = {"a": jnp.ones((2,)), "b": [jnp.zeros(())]}
-    doubled = compat.tree_map(lambda x: x * 2, tree)
-    assert float(doubled["a"][0]) == 2.0
-    leaves, treedef = compat.tree_flatten(tree)
-    assert len(leaves) == len(compat.tree_leaves(tree)) == 2
-    rebuilt = compat.tree_unflatten(treedef, leaves)
-    assert set(rebuilt) == {"a", "b"}
-
-
-def test_random_key_feeds_samplers():
-    k = compat.random_key(0)
-    out = jax.random.normal(k, (3,))
-    assert out.shape == (3,)
 
 
 # ---------------------------------------------------------------------------
@@ -235,29 +212,6 @@ def test_no_direct_drift_api_call_sites():
 # ---------------------------------------------------------------------------
 # Distributed runtime shim
 # ---------------------------------------------------------------------------
-
-def test_distributed_initialize_filters_kwargs_to_live_signature(monkeypatch):
-    """Keywords the installed ``jax.distributed.initialize`` doesn't take
-    are dropped; ``timeout_s`` is mapped onto ``initialization_timeout``
-    (an int of seconds) when the signature accepts it."""
-    calls = []
-
-    def fake_init(coordinator_address, num_processes, process_id,
-                  initialization_timeout=None):
-        calls.append(dict(coordinator_address=coordinator_address,
-                          num_processes=num_processes,
-                          process_id=process_id,
-                          initialization_timeout=initialization_timeout))
-
-    monkeypatch.setattr(jax.distributed, "initialize", fake_init)
-    ok = compat.distributed_initialize("127.0.0.1:9999", 2, 1,
-                                       timeout_s=5.7,
-                                       local_device_ids=[0])  # not in sig
-    assert ok is True
-    assert calls == [dict(coordinator_address="127.0.0.1:9999",
-                          num_processes=2, process_id=1,
-                          initialization_timeout=5)]
-
 
 def test_distributed_initialize_passes_extras_through_var_keyword(monkeypatch):
     calls = []

@@ -4,7 +4,6 @@ import jax.numpy as jnp
 import pytest
 
 from repro.analysis.hlo_cost import module_cost
-from repro.runtime import compat
 
 
 def _compile(f, *args):
@@ -16,7 +15,7 @@ def test_plain_matmul_matches_xla():
     b = jax.ShapeDtypeStruct((512, 128), jnp.float32)
     comp = _compile(lambda a, b: a @ b, a, b)
     mine = module_cost(comp.as_text())
-    assert mine.flops == pytest.approx(compat.cost_analysis(comp)["flops"])
+    assert mine.flops == pytest.approx(comp.cost_analysis()["flops"])
     assert mine.flops == pytest.approx(2 * 256 * 512 * 128)
 
 
@@ -30,7 +29,7 @@ def test_scan_multiplies_trip_count():
     mine = module_cost(comp.as_text())
     assert mine.flops == pytest.approx(48 * 2 * 128 ** 3, rel=0.01)
     # XLA's own counter misses the trip count
-    assert compat.cost_analysis(comp)["flops"] < mine.flops / 10
+    assert comp.cost_analysis()["flops"] < mine.flops / 10
 
 
 def test_nested_scans_multiply():

@@ -136,6 +136,36 @@ def test_repeat_offender_evicted_and_gang_remeshed(tmp_path):
     assert "evict" in kinds and "remesh" in kinds
 
 
+@pytest.mark.parametrize("platforms,nprocs,refused", [
+    ("", 2, True), ("cpu", 2, False), ("", 1, False), ("tpu", 2, True),
+    ("cpu,tpu", 2, True), (" CPU ", 2, False)])
+def test_gang_refused_on_tpu_host(tmp_path, monkeypatch, platforms, nprocs,
+                                  refused):
+    """One process per chip: every JAX worker would claim all of a TPU
+    host's chips, so the launchers refuse a gang of more than one there
+    unless ``JAX_PLATFORMS`` keeps the workers off ``tpu``.  The generic
+    Supervisor itself launches any process and refuses nothing."""
+    from repro.launch import supervisor as launcher
+    from repro.runtime import compat
+    monkeypatch.setattr(compat, "tpu_chips_attached", lambda: 4)
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    fleet = str(tmp_path / "fleet")
+    Supervisor(nprocs, _fake_builder(tmp_path, fleet, "pass\n"),
+               fleet_dir=fleet)
+    argv = ["--nprocs", str(nprocs), "--fleet-dir", fleet]
+    if refused:
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+            launcher.main(argv)
+        from types import SimpleNamespace
+
+        from repro.launch.serve import run_fleet_supervised
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+            run_fleet_supervised(SimpleNamespace(fleet=nprocs))
+    else:
+        from repro.launch.mesh import refuse_gang_on_tpu
+        refuse_gang_on_tpu(nprocs)
+
+
 def test_failure_budget_exhaustion_shuts_down(tmp_path):
     fleet = str(tmp_path / "fleet")
     build = _fake_builder(tmp_path, fleet, "sys.exit(2)\n")
@@ -438,8 +468,7 @@ def test_fleet_distributed_jax_smoke(tmp_path):
         pytest.skip("jax.distributed barrier timed out under load; "
                     "workers degraded to single-process as designed")
     for t, r in res.items():
-        # process_count, not device_count: a prior in-process import of
-        # launch.dryrun force-multiplies host devices via XLA_FLAGS and
-        # worker subprocesses inherit it — the barrier invariant is the
+        # process_count, not device_count: an inherited XLA_FLAGS may
+        # force-multiply host devices — the barrier invariant is the
         # number of JOINED PROCESSES.
         assert r["process_count"] == 2, r
