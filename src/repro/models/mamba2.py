@@ -165,27 +165,33 @@ def _split_proj(cfg: Mamba2Config, zxbcdt):
 
 def _mix_block(cfg: Mamba2Config, lp, x, conv_state=None, ssm_state=None,
                single_step: bool = False):
-    """One mamba2 mixer. x: (B, L, D) (or (B, 1, D) when single_step)."""
+    """One mamba2 mixer. x: (B, L, D) (or (B, 1, D) when single_step).
+    Its parts run under the named scopes ``in_proj``, ``conv``, ``ssd``
+    (the chunked scan), ``gate_norm`` and ``out_proj``, which a profiler
+    trace reports with each device op."""
     B, L, D = x.shape
     Din, N, H, P = cfg.d_inner, cfg.d_state, cfg.n_heads, cfg.headdim
-    zxbcdt = x @ lp["in_proj"]
-    z, xbc, dt = _split_proj(cfg, zxbcdt)
+    with jax.named_scope("in_proj"):
+        zxbcdt = x @ lp["in_proj"]
+        z, xbc, dt = _split_proj(cfg, zxbcdt)
 
-    if single_step:
-        # roll conv window: conv_state (B, d_conv-1, d_xbc)
-        win = jnp.concatenate([conv_state, xbc.astype(jnp.float32)], axis=1)
-        new_conv = win[:, 1:]
-        conv_w = lp["conv_w"].astype(jnp.float32)      # (d_conv, d_xbc)
-        xbc = jax.nn.silu((win * conv_w[None]).sum(1) +
-                          lp["conv_b"].astype(jnp.float32))[:, None]
-    else:
-        pad = jnp.zeros((B, cfg.d_conv - 1, cfg.d_xbc), jnp.float32)
-        seq = jnp.concatenate([pad, xbc.astype(jnp.float32)], axis=1)
-        conv_w = lp["conv_w"].astype(jnp.float32)
-        xbc = sum(seq[:, i:i + L] * conv_w[i][None, None]
-                  for i in range(cfg.d_conv))
-        xbc = jax.nn.silu(xbc + lp["conv_b"].astype(jnp.float32))
-        new_conv = seq[:, L:]  # unused in train
+    with jax.named_scope("conv"):
+        if single_step:
+            # roll conv window: conv_state (B, d_conv-1, d_xbc)
+            win = jnp.concatenate([conv_state, xbc.astype(jnp.float32)],
+                                  axis=1)
+            new_conv = win[:, 1:]
+            conv_w = lp["conv_w"].astype(jnp.float32)  # (d_conv, d_xbc)
+            xbc = jax.nn.silu((win * conv_w[None]).sum(1) +
+                              lp["conv_b"].astype(jnp.float32))[:, None]
+        else:
+            pad = jnp.zeros((B, cfg.d_conv - 1, cfg.d_xbc), jnp.float32)
+            seq = jnp.concatenate([pad, xbc.astype(jnp.float32)], axis=1)
+            conv_w = lp["conv_w"].astype(jnp.float32)
+            xbc = sum(seq[:, i:i + L] * conv_w[i][None, None]
+                      for i in range(cfg.d_conv))
+            xbc = jax.nn.silu(xbc + lp["conv_b"].astype(jnp.float32))
+            new_conv = seq[:, L:]  # unused in train
 
     xs = xbc[..., :Din].reshape(B, -1, H, P)
     Bm = xbc[..., Din:Din + N]
@@ -200,15 +206,19 @@ def _mix_block(cfg: Mamba2Config, lp, x, conv_state=None, ssm_state=None,
         ssm_state = dA[..., None, None] * ssm_state + Sc
         y = jnp.einsum("bn,bhpn->bhp", Cm[:, 0], ssm_state)[:, None]
     else:
-        y = _ssd_chunked(xs, dt, A, Bm, Cm, min(cfg.chunk, L))
+        with jax.named_scope("ssd"):
+            y = _ssd_chunked(xs, dt, A, Bm, Cm, min(cfg.chunk, L))
         if ssm_state is None:
             ssm_state = jnp.zeros((B, H, P, N), jnp.float32)
 
-    y = y + lp["D_skip"].astype(jnp.float32)[None, None, :, None] * xs
-    y = y.reshape(B, -1, Din)
-    y = y * jax.nn.silu(z.astype(jnp.float32))
-    y = rms_norm(y.astype(cfg.dtype), lp["gnorm"], cfg.norm_eps)
-    return y @ lp["out_proj"], new_conv, ssm_state
+    with jax.named_scope("gate_norm"):
+        y = y + lp["D_skip"].astype(jnp.float32)[None, None, :, None] * xs
+        y = y.reshape(B, -1, Din)
+        y = y * jax.nn.silu(z.astype(jnp.float32))
+        y = rms_norm(y.astype(cfg.dtype), lp["gnorm"], cfg.norm_eps)
+    with jax.named_scope("out_proj"):
+        out = y @ lp["out_proj"]
+    return out, new_conv, ssm_state
 
 
 def forward(cfg: Mamba2Config, params: dict, tokens: jax.Array,
@@ -227,8 +237,10 @@ def forward(cfg: Mamba2Config, params: dict, tokens: jax.Array,
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.nothing_saveable)
     x, _ = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    return x @ params["lm_head"], 0.0
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        logits = x @ params["lm_head"]
+    return logits, 0.0
 
 
 def init_cache(cfg: Mamba2Config, batch: int, max_len: int = 0,

@@ -313,7 +313,10 @@ def paged_step(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     included) in its first PRIVATE page — shared pages are never written.
     Pad/idle writes are routed to trash page 0.
 
-    Returns (logits (B, T, vocab), pool', lengths + counts)."""
+    Returns (logits (B, T, vocab), pool', lengths + counts).  The layer's
+    parts run under the named scopes ``qkv``, ``attention`` (pool write,
+    paged attention, output projection), ``mlp`` and ``lm_head``, which a
+    profiler trace reports with each device op."""
     x = params["embed"][tokens]
     B, T, _ = x.shape
     page = pool["k"].shape[2]
@@ -357,28 +360,31 @@ def paged_step(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         else:
             lp, kc, vc = inp
             ksc = vsc = None
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        q, k, v = _qkv(cfg, lp, h, positions)
-        q, k, v = replicate(q), replicate(k), replicate(v)
-        if quantized:
-            kq, ks_ = quantize_kv(k)
-            vq, vs_ = quantize_kv(v)
-            kc, vc = write(kc, kq), write(vc, vq)
-            ksc, vsc = write(ksc, ks_), write(vsc, vs_)
-            o = paged_decode_attention(q, kc, vc, page_table, lengths,
-                                       ksc, vsc, impl=impl)
-            out_pool = (kc, vc, ksc, vsc)
-        else:
-            kc, vc = write(kc, k), write(vc, v)
-            o = paged_decode_attention(q, kc, vc, page_table, lengths,
-                                       impl=impl)
-            out_pool = (kc, vc)
-        x = x + replicate(o).reshape(B, T, -1) @ lp["wo"]
-        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.moe:
-            mo, _ = moe_layer(h, lp, cfg.moe)
-        else:
-            mo = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+        with jax.named_scope("qkv"):
+            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+            q, k, v = _qkv(cfg, lp, h, positions)
+            q, k, v = replicate(q), replicate(k), replicate(v)
+        with jax.named_scope("attention"):
+            if quantized:
+                kq, ks_ = quantize_kv(k)
+                vq, vs_ = quantize_kv(v)
+                kc, vc = write(kc, kq), write(vc, vq)
+                ksc, vsc = write(ksc, ks_), write(vsc, vs_)
+                o = paged_decode_attention(q, kc, vc, page_table, lengths,
+                                           ksc, vsc, impl=impl)
+                out_pool = (kc, vc, ksc, vsc)
+            else:
+                kc, vc = write(kc, k), write(vc, v)
+                o = paged_decode_attention(q, kc, vc, page_table, lengths,
+                                           impl=impl)
+                out_pool = (kc, vc)
+            x = x + replicate(o).reshape(B, T, -1) @ lp["wo"]
+        with jax.named_scope("mlp"):
+            h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+            if cfg.moe:
+                mo, _ = moe_layer(h, lp, cfg.moe)
+            else:
+                mo = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
         # the residual carry stays replicated too: serving activations are
         # small, and this keeps GSPMD from threading pool-derived layouts
         # through the layer scan
@@ -393,8 +399,9 @@ def paged_step(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], pool["k"],
                                              pool["v"]))
         new_pool = {"k": ks, "v": vs}
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = x @ params["lm_head"]
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        logits = x @ params["lm_head"]
     return logits, new_pool, lengths + counts
 
 

@@ -6,7 +6,8 @@ Three layers (see docs/ARCHITECTURE.md "Observability"):
    process-wide :data:`~repro.obs.metrics.REGISTRY` that absorbs the
    repo's scattered ad-hoc stats;
 2. :mod:`repro.obs.trace` — nested span tracer (virtual-clock compatible)
-   with a Chrome trace-event JSON exporter perfetto can load;
+   with a Chrome trace-event JSON exporter perfetto can load; each span
+   is also mirrored into the JAX profiler's trace when JAX is loaded;
 3. :mod:`repro.obs.roofline_live` — observed-vs-predicted traffic rows
    that close the loop on the paper's fetch-reduction claims at runtime.
 
@@ -20,13 +21,23 @@ reads per tick (< 2% tick time — asserted by the smoke benchmark).
 handed a Telemetry explicitly (kernel wrappers, checkpoint manager) reach
 it through :func:`get_telemetry`.
 
+Program loads: while an enabled telemetry is installed, JAX's compile
+events become spans ``jit.trace`` (tracing a function to a jaxpr),
+``jit.lower`` (jaxpr to an MLIR module) and ``jit.compile`` (the backend
+compile, or the load of the executable from the persistent cache), each
+with the arg ``fun``, and the persistent cache's answers become the
+counters ``jit.cache_hits`` and ``jit.cache_misses``.  The listeners are
+registered once per process, by :func:`set_telemetry` with an enabled
+telemetry, and only if JAX is already imported.
+
 The package is deliberately jax-free so the host-side control modules
-that import it stay jax-free too.
+that import it stay jax-free too: it imports nothing of JAX itself.
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
 import time
 from contextlib import contextmanager
 from typing import Any, Callable
@@ -113,11 +124,62 @@ def get_telemetry() -> Telemetry:
 
 def set_telemetry(t: Telemetry | None) -> Telemetry:
     """Install ``t`` as the global (None restores the disabled default);
-    returns the previous one so scopes can put it back."""
+    returns the previous one so scopes can put it back.  An enabled ``t``
+    in a process that has imported JAX also starts the program-load
+    listeners (:class:`_JitListener`)."""
     global _default
     prev = _default
     _default = t if t is not None else _DISABLED
+    if _default.enabled and "jax" in sys.modules:
+        _JitListener.install()
     return prev
+
+
+class _JitListener:
+    """Turns JAX's compile events into spans and counters of the global
+    telemetry, once it is enabled and clocked by ``time.monotonic``.
+
+    JAX stamps its spans with ``time.time()``; the offset to
+    ``time.monotonic()`` is taken once, when the listener is made.  A
+    telemetry on another clock (the train launcher's per-step virtual
+    clock, whose traces replay bit for bit) records none of them."""
+
+    SPANS = {"/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+             "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+             "/jax/core/compile/backend_compile_duration": "jit.compile"}
+    COUNTERS = {"/jax/compilation_cache/cache_hits": "jit.cache_hits",
+                "/jax/compilation_cache/cache_misses": "jit.cache_misses"}
+
+    installed: "_JitListener | None" = None
+
+    def __init__(self):
+        self.offset = time.monotonic() - time.time()
+
+    @classmethod
+    def install(cls) -> None:
+        if cls.installed is not None:
+            return
+        import jax.monitoring
+        cls.installed = listener = cls()
+        jax.monitoring.register_event_time_span_listener(listener.on_span)
+        jax.monitoring.register_event_listener(listener.on_event)
+
+    @staticmethod
+    def _records(t: Telemetry, event: str, names: dict) -> bool:
+        return (t.enabled and event in names
+                and t.tracer.clock is time.monotonic)
+
+    def on_span(self, event: str, start: float, end: float, **kw) -> None:
+        t = _default
+        if self._records(t, event, self.SPANS):
+            t.tracer.complete(self.SPANS[event], start + self.offset,
+                              end + self.offset, cat="jit",
+                              fun=str(kw.get("fun_name", "")))
+
+    def on_event(self, event: str, **_) -> None:
+        t = _default
+        if self._records(t, event, self.COUNTERS):
+            t.counter(self.COUNTERS[event])
 
 
 def enable(*, clock: Callable[[], float] = time.monotonic,

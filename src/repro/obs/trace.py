@@ -20,22 +20,33 @@ events additionally carry ``dur``, ``"i"`` instants carry scope ``"s":
 "t"``, and per-thread ``"M"`` metadata events name the threads.  Span
 ``args`` pass straight through to the event's ``args`` (perfetto shows
 them in the selection panel).
+
+One span call feeds two sinks: when JAX is loaded, each span opened with
+:meth:`SpanTracer.begin` (or ``span``) also opens a
+``jax.profiler.TraceAnnotation`` of the same name, closed in the same
+nesting order, so a profiler trace shows the program's spans on the
+device's clock beside the device's work.  Instants are not mirrored.
+Spans timed elsewhere (JAX's compile events) come in through
+:meth:`SpanTracer.complete` and are not mirrored either.
 """
 from __future__ import annotations
 
 import functools
 import json
 import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Callable
 
+_UNRESOLVED = object()
+
 
 class _SpanHandle:
     """An open span (returned by :meth:`SpanTracer.begin`)."""
 
-    __slots__ = ("name", "cat", "t0", "tid", "args", "closed")
+    __slots__ = ("name", "cat", "t0", "tid", "args", "closed", "mirror")
 
     def __init__(self, name: str, cat: str, t0: float, tid: int,
                  args: dict):
@@ -45,6 +56,12 @@ class _SpanHandle:
         self.tid = tid
         self.args = args
         self.closed = False
+        self.mirror = None               # open profiler annotation, if any
+
+    def close_mirror(self) -> None:
+        if self.mirror is not None:
+            self.mirror.__exit__(None, None, None)
+            self.mirror = None
 
 
 class SpanTracer:
@@ -62,6 +79,7 @@ class SpanTracer:
         self._lock = threading.Lock()
         self._local = threading.local()      # per-thread span stack
         self._tids: dict[int, str] = {}      # tid -> thread name
+        self._annotation: Any = _UNRESOLVED  # profiler annotation class
 
     # -- internals ----------------------------------------------------------
 
@@ -79,6 +97,16 @@ class SpanTracer:
                 self._tids[tid] = t.name
         return tid
 
+    def _mirror_class(self):
+        """``jax.profiler.TraceAnnotation`` when jax was imported before
+        this tracer's first span, else None (looked up once)."""
+        if self._annotation is _UNRESOLVED:
+            self._annotation = None
+            if "jax" in sys.modules:
+                from jax.profiler import TraceAnnotation
+                self._annotation = TraceAnnotation
+        return self._annotation
+
     def _emit(self, ev: dict) -> None:
         with self._lock:
             if len(self._events) >= self.max_events:
@@ -92,7 +120,14 @@ class SpanTracer:
         """Open a span NOW; close it with :meth:`finish`.  For scopes that
         don't nest lexically (the train loop's RUN segment ends wherever
         the next fault begins)."""
+        # the annotation opens before the clock is read and closes after,
+        # so a span's duration leaves out its own annotation's cost
+        annotation = self._mirror_class()
+        mirror = annotation(name) if annotation is not None else None
+        if mirror is not None:
+            mirror.__enter__()
         h = _SpanHandle(name, cat, self.clock(), self._tid(), args)
+        h.mirror = mirror
         self._stack().append(h)
         return h
 
@@ -107,6 +142,7 @@ class SpanTracer:
             h = stack.pop()
             h.closed = True
             t1 = self.clock()
+            h.close_mirror()
             args = {**h.args, **(extra_args if h is handle else {})}
             self._emit({"name": h.name, "cat": h.cat, "ph": "X",
                         "ts": h.t0, "dur": max(0.0, t1 - h.t0),
@@ -116,11 +152,21 @@ class SpanTracer:
         # handle was not on this thread's stack (crossed threads): still
         # record it so the span is not silently lost
         handle.closed = True
+        t1 = self.clock()
+        handle.close_mirror()
         self._emit({"name": handle.name, "cat": handle.cat, "ph": "X",
-                    "ts": handle.t0,
-                    "dur": max(0.0, self.clock() - handle.t0),
+                    "ts": handle.t0, "dur": max(0.0, t1 - handle.t0),
                     "tid": handle.tid,
                     "args": {**handle.args, **extra_args}})
+
+    def complete(self, name: str, t0: float, t1: float, cat: str = "span",
+                 **args) -> None:
+        """Record a span that was timed elsewhere, from ``t0`` to ``t1``
+        on this tracer's clock, on the calling thread.  It joins no
+        stack and is not mirrored into the profiler."""
+        self._emit({"name": name, "cat": cat, "ph": "X", "ts": t0,
+                    "dur": max(0.0, t1 - t0), "tid": self._tid(),
+                    "args": args})
 
     @contextmanager
     def span(self, name: str, cat: str = "span", **args):

@@ -52,6 +52,14 @@ def clip_by_global_norm(grads: Any, max_norm: float) -> tuple[Any, jax.Array]:
 
 def adamw_update(cfg: AdamWConfig, params: Any, grads: Any,
                  state: dict) -> tuple[Any, dict, dict]:
+    """One AdamW step (clipping included), under the named scope
+    ``adamw``."""
+    with jax.named_scope("adamw"):
+        return _adamw_update(cfg, params, grads, state)
+
+
+def _adamw_update(cfg: AdamWConfig, params: Any, grads: Any,
+                  state: dict) -> tuple[Any, dict, dict]:
     grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
     grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
     step = state["step"] + 1

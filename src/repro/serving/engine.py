@@ -66,6 +66,9 @@ from .prefix import RadixPrefixCache
 from .scheduler import Phase, PhaseScheduler, Request, SchedulerConfig
 
 KV_MODES = ("dense", "paged", "paged_int8")
+# the token counters of ``ServingEngine.traffic_stats``
+_TRAFFIC_COUNTERS = ("gb_read_tokens", "dram_read_tokens", "written_tokens",
+                     "prefill_tokens", "decode_rows", "decode_keys")
 
 
 class _TracedPrefix:
@@ -343,8 +346,7 @@ class ServingEngine:
         self.queue: list[tuple[int, np.ndarray, int, int | None]] = []
         self._dense_tick = 0
         self._dense_cache = None
-        self._traffic = {"gb_read_tokens": 0, "dram_read_tokens": 0,
-                         "written_tokens": 0}
+        self._traffic = dict.fromkeys(_TRAFFIC_COUNTERS, 0)
         self._decode = jax.jit(self.bundle.decode_step)
         self._cache_axes: dict | None = None
         self._prefill_template = None       # built lazily, reused forever
@@ -538,8 +540,7 @@ class ServingEngine:
         # accumulated in _exec_rows.  Plain int adds — always on; the
         # roofline accountant compares them against the closed-form
         # prediction (obs.roofline_live.predict_paged_decode_traffic).
-        self._traffic = {"gb_read_tokens": 0, "dram_read_tokens": 0,
-                         "written_tokens": 0}
+        self._traffic = dict.fromkeys(_TRAFFIC_COUNTERS, 0)
 
     def _pages_view(self, max_tokens: int) -> int:
         """Power-of-two page-table slice covering ``max_tokens`` — the
@@ -655,7 +656,8 @@ class ServingEngine:
                                 prompt=int(len(req.prompt)),
                                 matched=int(req.matched_tokens))
                 if req.cow is not None:
-                    self._exec_cow(req)
+                    with obs.span("cow", rid=req.rid):
+                        self._exec_cow(req)
         shed = self.sched.drain_shed()
         for req in shed:
             self.results[req.rid] = req.output
@@ -694,37 +696,63 @@ class ServingEngine:
             groups = [(jobs, []), ([], decoding)]
         for g_jobs, g_decode in groups:
             if g_jobs or g_decode:
-                with obs.span("prefill" if g_jobs else "decode",
-                              tick=self.ticks, prefill_rows=len(g_jobs),
-                              decode_rows=len(g_decode)):
-                    self._exec_rows(g_jobs, g_decode)
+                self._exec_rows(g_jobs, g_decode)
 
     def _exec_rows(self, jobs, decoding) -> None:
         """Build one padded (B, T) batch from the given prefill jobs +
         decode rows, run it through ``paged_step``, and harvest: advance
-        lengths, sample next tokens, finish completed requests."""
-        cfg = self.cfg
-        B = cfg.batch
-        T = _pow2_at_least(max([j.count for j in jobs], default=1))
-        tokens = np.zeros((B, T), np.int32)
-        counts = np.zeros((B,), np.int32)
-        for j in jobs:
-            tokens[j.req.slot, :j.count] = \
-                j.req.prompt[j.start:j.start + j.count]
-            counts[j.req.slot] = j.count
-        for r in decoding:
-            tokens[r.slot, 0] = r.generated[-1]
-            counts[r.slot] = 1
-        mp = self._pages_view(max(
-            int(self.kv.lengths[s]) + int(counts[s])
-            for s in range(B) if counts[s] > 0))
-        rows_dev, picked_dev = self._exec_step(tokens, counts, mp)
-        picked = np.asarray(picked_dev) if self._greedy \
-            else np.asarray(rows_dev)
+        lengths, sample next tokens, finish completed requests.
 
+        Traced as one span named by the call's width (``decode`` for
+        T == 1, single-token prefills included, else ``prefill``) over
+        four host phases: ``rows.build`` (the batch and its page view),
+        ``rows.launch`` (the dispatch, which returns before the device
+        finishes), ``rows.wait`` (the host blocked on the device for the
+        picked tokens) and ``rows.commit`` (KV lengths, traffic, tokens,
+        finished requests).  A decode span also records how much of the
+        attention's page view is live: ``view_pages``, ``live_keys`` (the
+        keys the rows attend) and ``view_keys`` (slots x view)."""
+        obs = self.obs
+        B = self.cfg.batch
+        T = _pow2_at_least(max([j.count for j in jobs], default=1))
+        with obs.span("decode" if T == 1 else "prefill", tick=self.ticks,
+                      prefill_rows=len(jobs),
+                      decode_rows=len(decoding)) as group:
+            with obs.span("rows.build"):
+                tokens = np.zeros((B, T), np.int32)
+                counts = np.zeros((B,), np.int32)
+                for j in jobs:
+                    tokens[j.req.slot, :j.count] = \
+                        j.req.prompt[j.start:j.start + j.count]
+                    counts[j.req.slot] = j.count
+                for r in decoding:
+                    tokens[r.slot, 0] = r.generated[-1]
+                    counts[r.slot] = 1
+                mp = self._pages_view(max(
+                    int(self.kv.lengths[s]) + int(counts[s])
+                    for s in range(B) if counts[s] > 0))
+                if group is not None and T == 1:
+                    live = counts > 0
+                    group.args.update(
+                        view_pages=mp,
+                        live_keys=int((self.kv.lengths[live]
+                                       + counts[live]).sum()),
+                        view_keys=B * mp * self.kv.cfg.page_size)
+            with obs.span("rows.launch"):
+                rows_dev, picked_dev = self._exec_step(tokens, counts, mp)
+            with obs.span("rows.wait"):
+                picked = np.asarray(picked_dev) if self._greedy \
+                    else np.asarray(rows_dev)
+            with obs.span("rows.commit"):
+                self._commit_rows(jobs, decoding, counts, picked)
+
+    def _commit_rows(self, jobs, decoding, counts, picked) -> None:
+        """Harvest one executed batch: advance KV lengths, tally traffic,
+        append each row's next token and finish completed requests."""
+        cfg = self.cfg
         by_slot = {j.req.slot: j for j in jobs}
         tr, page = self._traffic, self.kv.cfg.page_size
-        for slot in range(B):
+        for slot in range(cfg.batch):
             if counts[slot] == 0:
                 continue
             job = by_slot.get(slot)
@@ -735,6 +763,7 @@ class ServingEngine:
                 tr["gb_read_tokens"] += ctx
                 tr["dram_read_tokens"] += self.kv.pages_for(ctx) * page
                 tr["written_tokens"] += job.count
+                tr["prefill_tokens"] += job.count
                 self.sched.finish_prefill_chunk(req, job.count)
                 if req.phase is not Phase.DECODE:
                     continue                         # more chunks to go
@@ -745,6 +774,8 @@ class ServingEngine:
                 tr["gb_read_tokens"] += ctx
                 tr["dram_read_tokens"] += self.kv.pages_for(ctx) * page
                 tr["written_tokens"] += 1
+                tr["decode_rows"] += 1
+                tr["decode_keys"] += ctx
             tok = int(picked[slot]) if self._greedy \
                 else self._pick(picked[slot])
             req.generated.append(tok)
@@ -886,7 +917,10 @@ class ServingEngine:
     def traffic_stats(self) -> dict:
         """Observed KV traffic (tokens + bytes) at the paper's two fetch
         levels: ``gb_*`` is token-exact attended context (global-buffer
-        level), ``dram_*`` is page-granular pool reads.  Paged modes
+        level), ``dram_*`` is page-granular pool reads.  Split by kind of
+        row: ``prefill_tokens`` (prompt positions computed in prefill
+        chunks), ``decode_rows`` (rows fed a generated token) and
+        ``decode_keys`` (the keys those rows attended).  Paged modes
         only; dense reports zeros (its cache is a flat reservation)."""
         tr = dict(self._traffic)
         if self.cfg.kv_mode != "dense":

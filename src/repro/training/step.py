@@ -49,16 +49,17 @@ def loss_fn(forward: Callable, params: Any, batch: dict,
     replicate (B, S, V) f32 on every chip.
     """
     logits, aux = forward(params, batch)
-    labels = batch["labels"]
-    T = labels.shape[1]
-    logits = logits[:, -T:].astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    vocab_iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
-    at_label = jnp.sum(
-        jnp.where(vocab_iota == labels[..., None], logits, 0.0), axis=-1)
-    ce = (logz - at_label).mean()
-    zloss = (logz ** 2).mean()
-    return ce + aux_weight * aux + z_weight * zloss, (ce, aux)
+    with jax.named_scope("loss"):
+        labels = batch["labels"]
+        T = labels.shape[1]
+        logits = logits[:, -T:].astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        vocab_iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+        at_label = jnp.sum(
+            jnp.where(vocab_iota == labels[..., None], logits, 0.0), axis=-1)
+        ce = (logz - at_label).mean()
+        zloss = (logz ** 2).mean()
+        return ce + aux_weight * aux + z_weight * zloss, (ce, aux)
 
 
 def make_train_step(forward: Callable, hyper: TrainHyper) -> Callable:

@@ -8,7 +8,11 @@ serve spans covering admission -> prefill -> decode -> completion,
 ``engine.telemetry()`` contents, and observed-vs-predicted roofline rows
 for one conv2d and one paged-decode workload within the documented
 tolerances."""
+import importlib.util
 import json
+import os
+import re
+import sys
 import threading
 
 import numpy as np
@@ -437,3 +441,274 @@ def test_gradguard_events_reach_registry(tmp_path):
     c = out["telemetry"]["counters"]
     assert c.get("gradguard_events{kind=skip,trigger=nonfinite}", 0) >= 1
     assert c.get("checkpoint_ops{op=save}", 0) >= 1
+
+
+# ---------------------------------------------------------------------------
+# one span call, two sinks: the profiler mirror
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Stands in for ``jax.profiler.TraceAnnotation``; returns the log of
+    (open | close, name) it records."""
+    import jax.profiler
+    log = []
+
+    class Recording:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("open", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("close", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recording)
+    return log
+
+
+def test_spans_mirror_into_profiler_annotations_in_nesting_order(
+        annotations):
+    tr, tick = _vclock_tracer()
+    outer = tr.begin("outer")
+    with tr.span("a"):
+        tr.instant("mark")                     # instants are not mirrored
+        tick()
+    tr.begin("b")
+    tr.begin("c")                              # both left open
+    tr.finish(outer)                           # force-closes c, then b
+    tr.complete("timed", 0.0, 1.0)             # timed elsewhere: no mirror
+    assert annotations == [("open", "outer"), ("open", "a"),
+                           ("close", "a"), ("open", "b"), ("open", "c"),
+                           ("close", "c"), ("close", "b"),
+                           ("close", "outer")]
+    assert [e["name"] for e in tr.spans()] == ["a", "c", "b", "outer",
+                                               "timed"]
+
+
+def test_disabled_telemetry_builds_no_annotation(annotations):
+    off = Telemetry(enabled=False, registry=MetricsRegistry())
+    with off.span("s"):
+        pass
+    off.finish(off.begin("b"))
+    assert annotations == []
+    on = Telemetry(enabled=True, registry=MetricsRegistry())
+    with on.span("s"):
+        pass
+    assert annotations == [("open", "s"), ("close", "s")]
+
+
+def test_tracer_mirrors_nothing_without_jax(annotations, monkeypatch):
+    monkeypatch.delitem(sys.modules, "jax")
+    tr, _ = _vclock_tracer()
+    with tr.span("s"):
+        pass
+    assert annotations == [] and len(tr.spans("s")) == 1
+
+
+# ---------------------------------------------------------------------------
+# program loads: JAX's compile events as spans and counters
+# ---------------------------------------------------------------------------
+
+def _listeners():
+    from jax._src import monitoring
+    return (len(monitoring.get_event_time_span_listeners()),
+            len(monitoring.get_event_listeners()))
+
+
+@pytest.fixture
+def compile_cache(tmp_path):
+    """JAX's persistent compile cache in a directory of its own, for one
+    test, every program written to it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    prev = {k: getattr(jax.config, k) for k in keys}
+    cc.reset_cache()
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "jax"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    yield
+    for k, v in prev.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+
+
+def test_program_loads_become_spans_and_cache_counters(compile_cache):
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    tel = obs.enable(process_name="unit")
+    after_enable = _listeners()
+    obs.enable(process_name="unit")            # once per process
+    tel = obs.get_telemetry()
+    assert _listeners() == after_enable
+
+    def loaded_program(x):
+        return jnp.tanh(x) * 3.0
+
+    x = np.arange(5, dtype=np.float32)
+    t0 = time.monotonic()
+    jax.jit(loaded_program)(x).block_until_ready()
+    jax.clear_caches()                         # the next call loads it
+    jax.jit(loaded_program)(x).block_until_ready()
+    t1 = time.monotonic()
+    mine = [e for e in tel.tracer.spans()
+            if "loaded_program" in e["args"].get("fun", "")]
+    by_phase = {n: [e for e in mine if e["name"] == n]
+                for n in ("jit.trace", "jit.lower", "jit.compile")}
+    assert all(len(v) == 2 for v in by_phase.values()), by_phase
+    for e in mine:                              # on the tracer's clock
+        assert e["cat"] == "jit"
+        assert t0 - 0.05 <= e["ts"] <= e["ts"] + e["dur"] <= t1 + 0.05
+    c = tel.snapshot()["counters"]
+    assert c.get("jit.cache_misses", 0) >= 1
+    assert c.get("jit.cache_hits", 0) >= 1
+
+
+def test_disabled_or_virtual_clock_telemetry_records_no_program_load():
+    import jax
+    import jax.numpy as jnp
+    before = _listeners()
+    obs.set_telemetry(Telemetry(enabled=False, registry=MetricsRegistry()))
+    if obs._JitListener.installed is None:     # first in this process
+        assert _listeners() == before          # disabled: none registered
+    obs.set_telemetry(None)
+    clk = [0.0]
+    tel = obs.enable(clock=lambda: clk[0], process_name="vclock")
+    jax.jit(lambda x: jnp.cos(x) + 7.0)(np.ones(3, np.float32))
+    assert tel.tracer.spans() == []            # replays stay bit-identical
+    obs.set_telemetry(None)
+    off = obs.get_telemetry()
+    jax.jit(lambda x: jnp.cos(x) + 8.0)(np.ones(3, np.float32))
+    assert off.tracer.events() == []
+
+
+# ---------------------------------------------------------------------------
+# serving: the tick's host phases and the decode call's live page view
+# ---------------------------------------------------------------------------
+
+def _harness_annotations():
+    path = os.path.join(os.path.dirname(__file__), "..", "bench",
+                        "trace_reduce.py")
+    spec = importlib.util.spec_from_file_location("_trace_reduce", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return set(mod.ANNOTATIONS)
+
+
+def _shared_prefix_serve():
+    """Two requests on one 12-token document, the second after the first
+    finished: a prefix-cache hit that ends mid-page, so a copy-on-write."""
+    from repro.launch.serve import build_engine
+    tel = Telemetry(enabled=True, registry=MetricsRegistry())
+    engine, vocab = build_engine(
+        "qwen3-4b", slots=3, max_len=64, max_new=5, kv_mode="paged",
+        page_size=8, prefill_chunk=8, prefix_cache=True, seed=0,
+        telemetry=tel)
+    rng = np.random.default_rng(0)
+    doc = rng.integers(0, vocab, 12).astype(np.int32)
+    for n in (3, 4):
+        engine.submit(np.concatenate(
+            [doc, rng.integers(0, vocab, n).astype(np.int32)]))
+        engine.run()
+    return engine, tel
+
+
+def test_paged_tick_spans_split_host_and_device_work():
+    engine, tel = _shared_prefix_serve()
+    assert engine.cow_copies == 1
+    spans = tel.tracer.spans()
+    names = {e["name"] for e in spans}
+    assert {"admission", "reclaim", "prefix_match", "cow", "prefill",
+            "decode", "rows.build", "rows.launch", "rows.wait",
+            "rows.commit"} <= names
+    assert not names & _harness_annotations()
+    cow = tel.tracer.spans("cow")[0]
+    admission = [a for a in tel.tracer.spans("admission")
+                 if a["ts"] <= cow["ts"]
+                 and cow["ts"] + cow["dur"] <= a["ts"] + a["dur"]]
+    assert len(admission) == 1                 # inside admission
+    for group in tel.tracer.spans("prefill") + tel.tracer.spans("decode"):
+        inside = [e["name"] for e in spans if e["name"].startswith("rows.")
+                  and group["ts"] <= e["ts"]
+                  and e["ts"] + e["dur"] <= group["ts"] + group["dur"]]
+        assert inside == ["rows.build", "rows.launch", "rows.wait",
+                          "rows.commit"]
+
+
+def test_decode_span_counts_the_live_page_view():
+    """One 9-token prompt, chunks of 8, 5 tokens: the 1-token second chunk
+    runs in the T == 1 call (a ``decode`` span with one prefill row),
+    then four decode rows; every row attends the tokens before it and
+    itself, under a view of 2 pages of 8 for each of the 3 slots."""
+    engine, tel, prompts, _ = _serve_traced(n_requests=1, prompt_len=9,
+                                            max_new=5, page_size=8,
+                                            prefill_chunk=8)
+    assert [e["args"]["prefill_rows"] for e in tel.tracer.spans("prefill")
+            ] == [1]
+    decode = [e["args"] for e in tel.tracer.spans("decode")]
+    assert [(a["prefill_rows"], a["decode_rows"]) for a in decode] == \
+        [(1, 0)] + [(0, 1)] * 4
+    assert [a["live_keys"] for a in decode] == [9, 10, 11, 12, 13]
+    assert all(a["view_pages"] == 2 and a["view_keys"] == 3 * 2 * 8
+               for a in decode)
+    tr = engine.traffic_stats()
+    assert tr["prefill_tokens"] == 9
+    assert tr["decode_rows"] == 4 and tr["decode_keys"] == 10 + 11 + 12 + 13
+
+
+# ---------------------------------------------------------------------------
+# named scopes in the train step
+# ---------------------------------------------------------------------------
+
+def _scopes_in(lowered) -> set[str]:
+    """The scopes over the ops of a lowered program: each component but
+    the last (the primitive) of the op names its locations carry, read
+    through transformations (``transpose(jvp(ssd))/mul``)."""
+    text = lowered.as_text(debug_info=True)
+    words = set()
+    for loc in re.findall(r'loc\("([^"]*/[^"]*)"', text):
+        for part in loc.split("/")[:-1]:
+            words.update(re.findall(r"[A-Za-z_][\w.\-]*", part))
+    return words
+
+
+def test_serving_step_program_carries_its_scopes():
+    """The one jitted serving step (decode width and prefill width) names
+    each layer's parts in its op names."""
+    import jax
+    import jax.numpy as jnp
+    from repro.configs import get_bundle
+    from repro.serving import engine as serving_engine
+    b = get_bundle("qwen3-4b", smoke=True)
+    params = b.abstract_params()
+    pool = jax.eval_shape(lambda: b.init_paged_pool(9, 8))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    for T in (1, 8):
+        low = serving_engine._pick_step.lower(
+            b.paged_step, params, i32(2, T), pool, i32(2, 4), i32(2), i32(2))
+        assert "jit__pick_step" in low.as_text()
+        assert {"qkv", "attention", "mlp", "lm_head"} <= _scopes_in(low)
+
+
+def test_mamba2_train_step_carries_its_scopes():
+    import jax
+    import jax.numpy as jnp
+    from repro import training
+    from repro.configs import get_bundle
+    from repro.optim import adamw_init
+    b = get_bundle("mamba2-370m", smoke=True)
+    params = b.abstract_params()
+    opt = jax.eval_shape(adamw_init, params)
+    tok = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    step = jax.jit(training.make_train_step(b.forward, training.TrainHyper()))
+    scopes = _scopes_in(step.lower(params, opt, {"tokens": tok,
+                                                 "labels": tok}))
+    assert {"in_proj", "conv", "ssd", "gate_norm", "out_proj", "lm_head",
+            "loss", "adamw"} <= scopes

@@ -14,6 +14,7 @@ while the module is imported.
 import dataclasses
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -56,9 +57,10 @@ def _struct(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_mosaic(fn, *args):
+def _assert_mosaic(fn, *args) -> str:
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def _flash_spec(S: int) -> katt.FlashSpec:
@@ -137,7 +139,12 @@ def test_qwen3_4b_paged_step_layer_compiles(one_chip, monkeypatch):
         return bundle.family.paged_step(cfg, params, tokens, pool, table,
                                         lengths, counts)
 
-    _assert_mosaic(step, params, tokens, pool, table, lengths, lengths)
+    # the kernel's op is named by its jitted wrapper, by which a trace's
+    # device ops (and the benchmark's decode readers) find it
+    text = _assert_mosaic(step, params, tokens, pool, table, lengths,
+                          lengths)
+    assert re.search(r"%paged_flash_decode(\.\d+)? = \S+ custom-call\(",
+                     text)
 
 
 def test_ring_fused_hop_compiles_on_2x2(topo, monkeypatch):
