@@ -197,26 +197,18 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     page_table (B, max_pages) physical page ids; lengths (B,) valid tokens;
     optional int8-pool scales (P, page, Hkv).  Returns (B, H, D).
 
-    Per-slot tables/lengths are replicated across kv heads so the kernel
-    grid can stay flat (b, kv head); the pool transposes to kv-head-major
-    so the page axis is the one the table indexes."""
+    The kernel reads the pools, table and lengths as they are stored; the
+    pages each grid step copies come from the page's bytes."""
     B, H, Dh = q.shape
     P, page_size, Hkv, _ = k_pages.shape
-    G = H // Hkv
-    qf = q.reshape(B, Hkv, G, Dh).reshape(B * Hkv, G, Dh)
-    kt = k_pages.transpose(2, 0, 1, 3)        # (Hkv, P, page, D)
-    vt = v_pages.transpose(2, 0, 1, 3)
-    pt = jnp.repeat(page_table.astype(jnp.int32), Hkv, axis=0)
-    lens = jnp.repeat(lengths.astype(jnp.int32), Hkv)
-    ks = vs = None
-    if k_scale is not None:
-        ks = k_scale.transpose(0, 2, 1)       # (P, Hkv, page)
-        vs = v_scale.transpose(0, 2, 1)
+    MP = int(page_table.shape[1])
+    ppb = _paged_attention.pages_per_block(
+        page_size, Hkv, Dh, k_pages.dtype.itemsize, MP)
     _record_dispatch("paged_flash_decode",
                      impl="int8" if k_scale is not None else "pallas",
                      batch=B, pages=P, page_size=page_size,
-                     max_pages=int(page_table.shape[1]))
-    out = _paged_attention.paged_flash_decode_pallas(
-        qf, kt, vt, pt, lens, ks, vs, page_size=page_size,
+                     max_pages=MP, pages_per_block=ppb)
+    return _paged_attention.paged_flash_decode_pallas(
+        q, k_pages, v_pages, page_table.astype(jnp.int32),
+        lengths.astype(jnp.int32), k_scale, v_scale, pages_per_block=ppb,
         interpret=_interpret())
-    return out.reshape(B, Hkv, G, Dh).reshape(B, H, Dh)

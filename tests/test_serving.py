@@ -32,40 +32,99 @@ def _pool_setup(seed=0, B=3, H=8, Hkv=2, Dh=32, P=12, pg=16, MP=4):
     return q, k, v, pt, lens
 
 
-def test_paged_kernel_matches_ref():
+# (Hkv, G, Dh) of the kernel cases; page 16, blocks of 2 pages (32 tokens)
+# over a 5-page view, so the view is not a multiple of the block
+KERNEL_SHAPES = [(2, 4, 32), (1, 5, 64), (8, 4, 128), (4, 1, 128)]
+KERNEL_PPB, KERNEL_MP = 2, 5
+# length 1, on a block edge, one past it, the whole view
+KERNEL_LENS = (1, 32, 33, 80)
+GARBAGE = (17, 18)      # mapped by no slot; the dead table points at them
+
+
+def _kernel_case(Hkv, G, Dh, seed=0, pg=16):
+    """q, pools, table, lengths and int8 pools with their scales.  Each
+    slot's live pages are its own; its table past the length points at
+    trash page 0 and at the unmapped GARBAGE pages."""
+    rng = np.random.default_rng(seed)
+    B, P = len(KERNEL_LENS), 20
+    q = jnp.asarray(rng.normal(size=(B, Hkv * G, Dh)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(P, pg, Hkv, Dh)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(P, pg, Hkv, Dh)), jnp.float32)
+    table = np.zeros((B, KERNEL_MP), np.int32)
+    free = iter(range(1, min(GARBAGE)))
+    for b, n in enumerate(KERNEL_LENS):
+        live = -(-n // pg)
+        table[b, :live] = [next(free) for _ in range(live)]
+        table[b, live:] = ([0] + list(GARBAGE) * KERNEL_MP)[:KERNEL_MP - live]
+    int8 = tuple(
+        jnp.asarray(rng.integers(-127, 127, (P, pg, Hkv, Dh)), jnp.int8)
+        for _ in range(2)) + tuple(
+        jnp.asarray(rng.uniform(0.01, 0.02, (P, pg, Hkv)), jnp.float32)
+        for _ in range(2))
+    return q, k, v, jnp.asarray(table), jnp.asarray(KERNEL_LENS), int8
+
+
+def _kernel(ppb, *args):
+    """The kernel with ``ppb`` pages a block, or through the jitted
+    wrapper (pages per block from the shapes) when ``ppb`` is None."""
     from repro.kernels import ops
+    from repro.kernels import paged_attention as kpaged
+    if ppb is None:
+        return ops.paged_flash_decode(*args)
+    return kpaged.paged_flash_decode_pallas(*args, pages_per_block=ppb,
+                                            interpret=True)
+
+
+_KERNEL_CASES = ([pytest.param(None, None, id="pool_setup")]
+                 + [pytest.param(s, ppb, id=f"{s}-ppb{ppb or 'auto'}")
+                    for s in KERNEL_SHAPES for ppb in (KERNEL_PPB, None)])
+
+
+def _case_args(shape, int8=False):
+    if shape is None:
+        q, k, v, pt, lens = _pool_setup()
+        if not int8:
+            return q, k, v, pt, lens
+        rng = np.random.default_rng(1)
+        P, pg, Hkv, Dh = 12, 16, 2, 32
+        kq = jnp.asarray(rng.integers(-127, 127, (P, pg, Hkv, Dh)), jnp.int8)
+        vq = jnp.asarray(rng.integers(-127, 127, (P, pg, Hkv, Dh)), jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.01, 0.02, (P, pg, Hkv)), jnp.float32)
+        vs = jnp.asarray(rng.uniform(0.01, 0.02, (P, pg, Hkv)), jnp.float32)
+        return q, kq, vq, pt, lens, ks, vs
+    q, k, v, pt, lens, (kq, vq, ks, vs) = _kernel_case(*shape)
+    return (q, kq, vq, pt, lens, ks, vs) if int8 else (q, k, v, pt, lens)
+
+
+@pytest.mark.parametrize("shape,ppb", _KERNEL_CASES)
+def test_paged_kernel_matches_ref(shape, ppb):
     from repro.kernels.ref import paged_decode_ref
-    q, k, v, pt, lens = _pool_setup()
-    out = ops.paged_flash_decode(q, k, v, pt, lens)
-    ref = paged_decode_ref(q, k, v, pt, lens)
+    args = _case_args(shape)
+    out = _kernel(ppb, *args)
+    ref = paged_decode_ref(*args)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-def test_paged_kernel_int8_matches_ref():
-    from repro.kernels import ops
+@pytest.mark.parametrize("shape,ppb", _KERNEL_CASES)
+def test_paged_kernel_int8_matches_ref(shape, ppb):
     from repro.kernels.ref import paged_decode_ref
-    q, _, _, pt, lens = _pool_setup()
-    rng = np.random.default_rng(1)
-    P, pg, Hkv, Dh = 12, 16, 2, 32
-    kq = jnp.asarray(rng.integers(-127, 127, (P, pg, Hkv, Dh)), jnp.int8)
-    vq = jnp.asarray(rng.integers(-127, 127, (P, pg, Hkv, Dh)), jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.01, 0.02, (P, pg, Hkv)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.01, 0.02, (P, pg, Hkv)), jnp.float32)
-    out = ops.paged_flash_decode(q, kq, vq, pt, lens, ks, vs)
-    ref = paged_decode_ref(q, kq, vq, pt, lens, ks, vs)
+    args = _case_args(shape, int8=True)
+    out = _kernel(ppb, *args)
+    ref = paged_decode_ref(*args)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-def test_paged_attention_trash_page_isolated():
-    """Pages beyond a slot's length (incl. trash page 0) never leak into
-    the output: doubling garbage in unmapped pages leaves results bitwise
-    identical."""
-    from repro.kernels import ops
-    q, k, v, pt, lens = _pool_setup()
-    out1 = ops.paged_flash_decode(q, k, v, pt, lens)
-    k2 = k.at[0].mul(2.0).at[10, :, :, :].add(7.0)   # trash + unmapped page
-    v2 = v.at[0].mul(-3.0).at[11, :, :, :].add(1.0)
-    out2 = ops.paged_flash_decode(q, k2, v2, pt, lens)
+@pytest.mark.parametrize("shape,ppb", _KERNEL_CASES)
+def test_paged_attention_trash_page_isolated(shape, ppb):
+    """Pages beyond a slot's length (trash page 0, and garbage pages the
+    dead table points at or no table maps) never leak into the output:
+    changing them leaves results bitwise identical."""
+    q, k, v, pt, lens = _case_args(shape)
+    out1 = _kernel(ppb, q, k, v, pt, lens)
+    k2, v2 = k.at[0].mul(2.0), v.at[0].mul(-3.0)
+    for g in ((10, 11) if shape is None else GARBAGE):
+        k2, v2 = k2.at[g].add(7.0), v2.at[g].add(1.0)
+    out2 = _kernel(ppb, q, k2, v2, pt, lens)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
 
 
@@ -257,6 +316,43 @@ def test_paged_vs_dense_equivalence():
     int8, _ = _serve("qwen3-4b", "paged_int8", prompts)
     assert sorted(int8) == sorted(dense)
     assert all(len(v) == 6 for v in int8.values())
+
+
+@pytest.mark.parametrize("kv_mode", ["paged", "paged_int8"])
+def test_paged_engine_pallas_matches_xla(kv_mode, monkeypatch):
+    """Greedy tokens are identical with the decode kernel forced on
+    (interpreted) and with the XLA gather path, over ticks that mix
+    prefill chunks and decode rows.  Blocks are cut to 2 pages (16
+    tokens), so the slots' lengths cross block edges as they grow."""
+    import dataclasses as dc
+
+    from repro.kernels import paged_attention as kpaged
+    from repro.launch import serve as launch_serve
+    from repro.obs import REGISTRY
+    real_bundle = launch_serve.get_bundle
+    page_bytes = 8 * 2 * 16 * (1 if kv_mode == "paged_int8" else 4)
+    monkeypatch.setattr(kpaged, "BLOCK_BYTES", 2 * page_bytes)
+    jax.clear_caches()
+
+    def serve(impl):
+        monkeypatch.setattr(
+            launch_serve, "get_bundle", lambda arch, smoke=False: dc.replace(
+                real_bundle(arch, smoke), cfg=dc.replace(
+                    real_bundle(arch, smoke).cfg, attn_impl=impl)))
+        return _serve("qwen3-4b", kv_mode, _prompts(n=4, seed=5),
+                      prefill_chunk=8)[0]
+
+    before = REGISTRY.get_counter("kernel_dispatch",
+                                  kernel="paged_flash_decode",
+                                  impl="int8" if kv_mode == "paged_int8"
+                                  else "pallas")
+    xla, pallas = serve("xla"), serve("pallas")
+    jax.clear_caches()
+    assert REGISTRY.get_counter(
+        "kernel_dispatch", kernel="paged_flash_decode",
+        impl="int8" if kv_mode == "paged_int8" else "pallas") > before
+    assert pallas == xla
+    assert all(len(v) == 6 for v in pallas.values())
 
 
 def test_engine_preemption_under_page_pressure():

@@ -94,21 +94,28 @@ def test_flash_forward_backward_compiles(one_chip):
 @pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
 def test_paged_decode_compiles(one_chip, kv_dtype):
-    slots, page, per_slot = 8, 16, 64
-    n_pages = slots * per_slot + 1
-    q = _struct((slots * HKV, G, DH), jnp.bfloat16, one_chip)
-    pool = _struct((HKV, n_pages, page, DH), kv_dtype, one_chip)
-    table = _struct((slots * HKV, per_slot), jnp.int32, one_chip)
-    lengths = _struct((slots * HKV,), jnp.int32, one_chip)
-    args = [q, pool, pool, table, lengths]
-    if kv_dtype == jnp.int8:
-        scales = _struct((n_pages, HKV, page), jnp.float32, one_chip)
-        args += [scales, scales]
+    """The pool as the serving step stores it, (P, page, Hkv, Dh), and
+    per-slot (B, MP) tables, at a view of 64 pages and at docqa's 394,
+    which is no multiple of the block."""
+    slots, page = 8, 16
+    for per_slot in (64, 394):
+        n_pages = slots * per_slot + 1
+        ppb = kpaged.pages_per_block(page, HKV, DH,
+                                     jnp.dtype(kv_dtype).itemsize, per_slot)
+        assert per_slot == 64 or per_slot % ppb
+        q = _struct((slots, H, DH), jnp.bfloat16, one_chip)
+        pool = _struct((n_pages, page, HKV, DH), kv_dtype, one_chip)
+        table = _struct((slots, per_slot), jnp.int32, one_chip)
+        lengths = _struct((slots,), jnp.int32, one_chip)
+        args = [q, pool, pool, table, lengths]
+        if kv_dtype == jnp.int8:
+            scales = _struct((n_pages, page, HKV), jnp.float32, one_chip)
+            args += [scales, scales]
 
-    def decode(*a):
-        return kpaged.paged_flash_decode_pallas(*a, page_size=page)
+        def decode(*a, ppb=ppb):
+            return kpaged.paged_flash_decode_pallas(*a, pages_per_block=ppb)
 
-    _assert_mosaic(decode, *args)
+        _assert_mosaic(decode, *args)
 
 
 def test_qwen3_4b_paged_step_layer_compiles(one_chip, monkeypatch):
@@ -145,6 +152,13 @@ def test_qwen3_4b_paged_step_layer_compiles(one_chip, monkeypatch):
                           lengths)
     assert re.search(r"%paged_flash_decode(\.\d+)? = \S+ custom-call\(",
                      text)
+    # the kernel reads the pool in place: no transpose or copy of a
+    # whole layer pool, in its stored or in a kv-head-major shape
+    n_pages = slots * per_slot + 1
+    pools = {f"[{n_pages},{page},{HKV},{DH}]", f"[{HKV},{n_pages},{page},{DH}]"}
+    relaid = [m.group(1) for m in re.finditer(
+        r"= \w+(\[[\d,]+\])\{[^}]*\} (?:copy|transpose)\(", text)]
+    assert not pools & set(relaid), relaid
 
 
 def test_ring_fused_hop_compiles_on_2x2(topo, monkeypatch):
