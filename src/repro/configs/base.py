@@ -171,6 +171,9 @@ class ArchBundle:
     sub_quadratic: bool = False
     kv_dtype_decode: Any = None  # e.g. jnp.int8 for big dense decode
     extras: dict = dataclasses.field(default_factory=dict)
+    # the device mesh the training forward shards over (``on_mesh``);
+    # None: whatever mesh the caller has entered, if any
+    mesh: Any = None
 
     # -- params ------------------------------------------------------------
     def init_params(self, key: jax.Array):
@@ -189,8 +192,31 @@ class ArchBundle:
             return self.cfg.active_param_count()
         return self.param_count()
 
+    def on_mesh(self, mesh) -> "ArchBundle":
+        """This bundle with its training forward traced on ``mesh``, so
+        that any caller that jits ``make_train_step(bundle.forward)`` runs
+        the step sharded by the program's plan (``param_shardings``)."""
+        return dataclasses.replace(self, mesh=mesh)
+
+    def param_shardings(self):
+        """The parameters' placement on ``mesh``: ``param_specs`` as
+        ``NamedSharding``s (the AdamW moments mirror it)."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from repro.parallel.sharding import param_specs
+        specs = param_specs(self.kind, self.abstract_params(), self.mesh)
+        return jax.tree.map(lambda s: NamedSharding(self.mesh, s), specs,
+                            is_leaf=lambda x: isinstance(x, PartitionSpec))
+
     # -- steps ---------------------------------------------------------------
     def forward(self, params, batch):
+        if self.mesh is None:
+            return self._forward(params, batch)
+        from repro.runtime import compat
+        with compat.use_abstract_mesh(self.mesh.abstract_mesh):
+            return self._forward(params, batch)
+
+    def _forward(self, params, batch):
         if self.kind == "audio":
             return self.family.forward(self.cfg, params, batch["tokens"],
                                        batch["frames"])

@@ -78,10 +78,17 @@ def plan_kernel(op: TensorOp, *, vmem_budget_bytes: int = 64 * 1024 * 1024,
                       grid=grid, dims_order=order.order)
 
 
+# the attention tile search's VMEM budget for 2-byte operands; wider
+# operands get a proportionally smaller one, so the flash backward's dk/dv
+# kernel (a GQA group of q and do blocks plus four score-sized f32 tiles)
+# stays inside Mosaic's 16 MiB of scoped VMEM: float32 at the 4 MiB blocks
+# (512, 1024) asked 17.07 MiB on a v5e
+ATTN_VMEM_BUDGET = 4 * 1024 * 1024
+
+
 @functools.lru_cache(maxsize=4096)
 def attention_block_shapes(q_len: int, kv_len: int, head_dim: int,
-                           *, vmem_budget_bytes: int = 4 * 1024 * 1024
-                           ) -> tuple[int, int]:
+                           *, itemsize: int = 2) -> tuple[int, int]:
     """(block_q, block_k) for a flash-attention score tile, TPU-aligned.
 
     Runs the paper's tile search on the QK^T NDRange (head dim is the
@@ -90,8 +97,11 @@ def attention_block_shapes(q_len: int, kv_len: int, head_dim: int,
     the scheduler engine's structural-key cache, so every decoder layer of
     an LM resolves its blocks with a dict lookup.  Blocks clamp to
     [SUBLANE, 512] x [LANE, 1024] and to the (padded) problem size —
-    the flash kernels pad ragged tails and mask them via kv_len/q_len."""
+    the flash kernels pad ragged tails and mask them via kv_len/q_len.
+    ``itemsize`` is the operands' width in bytes; it scales the VMEM
+    budget (``ATTN_VMEM_BUDGET`` at 2 bytes)."""
     from .ndrange import attention_scores_op
+    vmem_budget_bytes = ATTN_VMEM_BUDGET * 2 // max(2, itemsize)
     q_cap = max(SUBLANE, min(512, q_len))
     k_cap = max(LANE if kv_len >= LANE else pow2_floor(kv_len),
                 min(1024, kv_len))

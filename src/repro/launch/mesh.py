@@ -18,14 +18,18 @@ def make_local_mesh():
     return compat.make_mesh((1, 1), ("data", "model"))
 
 
-def make_host_mesh():
-    """(1, n) mesh over every device of this host: the model axis spans
-    all n chips, so parameters shard tensor-parallel and long sequences
-    take the ring (``RingAttnPolicy``) across them."""
+def make_host_mesh(n: int | None = None):
+    """(1, n) mesh over the first ``n`` devices of this host (all of them
+    when ``n`` is None): the model axis spans the n chips, so parameters
+    shard tensor-parallel and long sequences take the ring
+    (``RingAttnPolicy``) across them."""
     import jax
-    n = len(jax.local_devices())
-    return compat.make_mesh((1, n), ("data", "model"),
-                            devices=jax.local_devices())
+    local = jax.local_devices()
+    n = len(local) if n is None else n
+    if not 1 <= n <= len(local):
+        raise ValueError(f"a host mesh of {n} devices; this host has "
+                         f"{len(local)}")
+    return compat.make_mesh((1, n), ("data", "model"), devices=local[:n])
 
 
 def make_worker_mesh():

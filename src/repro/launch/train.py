@@ -69,7 +69,6 @@ from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import (make_host_mesh, make_local_mesh,
                                make_production_mesh, make_worker_mesh)
 from repro.optim import AdamWConfig, adamw_init
-from repro.parallel.sharding import param_specs
 from repro.runtime import (ChaosInjector, ChaosKilled, FleetWorker,
                            HeartbeatMonitor, StragglerPolicy, compat,
                            plan_elastic_remesh, tree_fingerprint)
@@ -118,9 +117,8 @@ def run(arch: str, *, smoke: bool = True, steps: int = 20,
     n_devices = jax.device_count()
     n_procs = jax.process_count()
 
-    pspecs = param_specs(bundle.kind, bundle.abstract_params(), mesh)
-    psh = jax.tree.map(lambda s: NamedSharding(mesh, s), pspecs,
-                       is_leaf=lambda x: isinstance(x, P))
+    bundle = bundle.on_mesh(mesh)
+    psh = bundle.param_shardings()
     tree_sh = {"params": psh,
                "opt": {"mu": psh, "nu": psh,
                        "step": NamedSharding(mesh, P())}}

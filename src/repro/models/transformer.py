@@ -141,32 +141,40 @@ def _qkv(cfg: TransformerConfig, lp: dict, x: jax.Array, positions):
 
 
 def _block_train(cfg: TransformerConfig, x, lp, positions):
-    h = gather_seq(rms_norm(x, lp["ln1"], cfg.norm_eps))
-    q, k, v = _qkv(cfg, lp, h, positions)
-    o = attention(q, k, v, causal=True, window=cfg.window,
-                  impl=cfg.attn_impl, ring=cfg.ring_attn)
-    # saved by the remat policy: backward reuses the attention output
-    # instead of re-streaming the whole flash pipeline (§Perf B1)
-    from jax.ad_checkpoint import checkpoint_name
-    o = checkpoint_name(o, "attn_out")
-    # Megatron-SP residual stream: the carry x stays SEQUENCE-SHARDED and
-    # only the deltas are resharded before the add — GSPMD then lowers the
-    # wo / w_down partial-sum contractions as reduce-scatter instead of
-    # all-reduce (16x fewer collective bytes; §Perf B2).
-    x = x + shard_seq(o.reshape(*x.shape[:2], -1) @ lp["wo"])
-    h = gather_seq(rms_norm(x, lp["ln2"], cfg.norm_eps))
-    if cfg.moe:
-        mo, aux = moe_layer(h, lp, cfg.moe)
-    else:
-        mo, aux = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]), 0.0
-    return x + shard_seq(mo), aux
+    """One training layer under the named scopes ``qkv``, ``attention``
+    (attention and the output projection) and ``mlp``, as in
+    ``paged_step``."""
+    with jax.named_scope("qkv"):
+        h = gather_seq(rms_norm(x, lp["ln1"], cfg.norm_eps))
+        q, k, v = _qkv(cfg, lp, h, positions)
+    with jax.named_scope("attention"):
+        o = attention(q, k, v, causal=True, window=cfg.window,
+                      impl=cfg.attn_impl, ring=cfg.ring_attn)
+        # saved by the remat policy: backward reuses the attention output
+        # instead of re-streaming the whole flash pipeline (§Perf B1)
+        from jax.ad_checkpoint import checkpoint_name
+        o = checkpoint_name(o, "attn_out")
+        # Megatron-SP residual stream: the carry x stays SEQUENCE-SHARDED
+        # and only the deltas are resharded before the add — GSPMD then
+        # lowers the wo / w_down partial-sum contractions as reduce-scatter
+        # instead of all-reduce (16x fewer collective bytes; §Perf B2).
+        x = x + shard_seq(o.reshape(*x.shape[:2], -1) @ lp["wo"])
+    with jax.named_scope("mlp"):
+        h = gather_seq(rms_norm(x, lp["ln2"], cfg.norm_eps))
+        if cfg.moe:
+            mo, aux = moe_layer(h, lp, cfg.moe)
+        else:
+            mo, aux = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]), 0.0
+        return x + shard_seq(mo), aux
 
 
 def forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
             vision_embeds: jax.Array | None = None):
     """tokens: (B, S_text) int32 -> logits (B, S, vocab), aux_loss.
 
-    For VLM configs, ``vision_embeds`` (B, P, D) is prepended (stub ViT)."""
+    For VLM configs, ``vision_embeds`` (B, P, D) is prepended (stub ViT).
+    Each layer runs under the scopes of ``_block_train``; the final norm
+    and the output head under ``lm_head``."""
     x = params["embed"][tokens]
     if vision_embeds is not None:
         x = jnp.concatenate([vision_embeds.astype(x.dtype), x], axis=1)
@@ -184,8 +192,9 @@ def forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
             policy=jax.checkpoint_policies.save_only_these_names(
                 "attn_out"))
     (x, aux), _ = jax.lax.scan(body, (x, 0.0), params["layers"])
-    x = rms_norm(x, params["ln_f"], cfg.norm_eps)
-    logits = x @ params["lm_head"]
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["ln_f"], cfg.norm_eps)
+        logits = x @ params["lm_head"]
     return logits, aux
 
 

@@ -36,7 +36,10 @@ def cosine_schedule(cfg: AdamWConfig, step: jax.Array) -> jax.Array:
 
 
 def adamw_init(params: Any) -> dict:
-    zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+    # zeros computed from each (finite) parameter, not a constant, so that
+    # under jit every moment takes its parameter's sharding; a constant
+    # would land on one device
+    zeros = jax.tree.map(lambda p: p.astype(jnp.float32) * 0.0, params)
     return {"mu": zeros,
             "nu": jax.tree.map(jnp.copy, zeros),
             "step": jnp.zeros((), jnp.int32)}

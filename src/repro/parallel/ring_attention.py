@@ -25,6 +25,10 @@ Backward (a second ring pass with the same hop schedule):
   is what kept the ring opt-in until this VJP).  ``impl='naive'`` keeps
   that path alive as the benchmark baseline.
 
+Each hop, its fold and its ppermutes, runs under the named scope
+``ring_hop`` (:data:`HOP_SCOPE`) in both passes, so a profiler trace
+tells the hop's device time apart from the rest of attention.
+
 Masking (causal / sliding-window) and GQA grouping are handled here so
 callers (``models/layers.attention``) only pick a policy; the varying-
 manual-axes typing inside shard_map goes through ``compat.pcast`` /
@@ -42,7 +46,10 @@ from jax.sharding import PartitionSpec as P
 
 from repro.runtime import compat
 
-__all__ = ["ring_attention", "data_axes_spec"]
+__all__ = ["ring_attention", "data_axes_spec", "HOP_SCOPE"]
+
+# the named scope over each hop's fold and its ppermutes, in both passes
+HOP_SCOPE = "ring_hop"
 
 
 def data_axes_spec(mesh, batch: int):
@@ -80,11 +87,13 @@ def _hop_perm(m: int):
     return [(i, (i + 1) % m) for i in range(m)]
 
 
-def _fused_blocks(S_l: int, Dh: int) -> tuple[int, int] | None:
-    """Autotuned (block_q, block_k) snapped down to divisors of the local
-    shard, or None when the shard is too ragged to tile (-> einsum fold)."""
+def _fused_blocks(S_l: int, Dh: int,
+                  itemsize: int = 2) -> tuple[int, int] | None:
+    """Autotuned (block_q, block_k) for operands of ``itemsize`` bytes,
+    snapped down to divisors of the local shard, or None when the shard is
+    too ragged to tile (-> einsum fold)."""
     from repro.core.pallas_bridge import attention_block_shapes
-    bq, bk = attention_block_shapes(S_l, S_l, Dh)
+    bq, bk = attention_block_shapes(S_l, S_l, Dh, itemsize=itemsize)
     while bq > 1 and S_l % bq:
         bq //= 2
     while bk > 1 and S_l % bk:
@@ -146,6 +155,7 @@ def _fused_fwd_body(spec: _RingSpec, q_l, k_l, v_l):
     q_off = idx * S_l
     perm = _hop_perm(spec.m)
 
+    @jax.named_scope(HOP_SCOPE)
     def step(t, carry):
         k_c, v_c, o_acc, lse = carry
         owner = (idx - t) % spec.m if needs_pos else 0
@@ -190,6 +200,7 @@ def _fused_bwd_body(spec: _RingSpec, q_l, k_l, v_l, o_l, lse_l, do_l):
     q_off = idx * S_l
     perm = _hop_perm(spec.m)
 
+    @jax.named_scope(HOP_SCOPE)
     def step(t, carry):
         k_c, v_c, dk_c, dv_c, dq = carry
         owner = (idx - t) % spec.m if needs_pos else 0
@@ -236,6 +247,7 @@ def _fwd_body(spec: _RingSpec, q_l, k_l, v_l):
     q_off = idx * S_l
     perm = _hop_perm(spec.m)
 
+    @jax.named_scope(HOP_SCOPE)
     def step(t, carry):
         k_c, v_c, mx, l, acc = carry
         owner = (idx - t) % spec.m
@@ -296,6 +308,7 @@ def _bwd_body(spec: _RingSpec, q_l, k_l, v_l, o_l, lse_l, do_l):
     q_off = idx * S_l
     perm = _hop_perm(spec.m)
 
+    @jax.named_scope(HOP_SCOPE)
     def step(t, carry):
         k_c, v_c, dk_c, dv_c, dq = carry
         owner = (idx - t) % spec.m
@@ -385,7 +398,8 @@ _ring_attn.defvjp(_ring_attn_fwd, _ring_attn_bwd)
 # public entry
 # ---------------------------------------------------------------------------
 
-def _decide_fused(fused: bool | None, S_global: int, S_local: int, Dh: int):
+def _decide_fused(fused: bool | None, S_global: int, S_local: int, Dh: int,
+                  itemsize: int = 2):
     """Resolve the per-hop fold engine: explicit ``fused`` wins, else the
     flash policy (REPRO_FLASH_ATTN / backend) judged on the GLOBAL
     sequence (the ring still folds all of it, one shard per hop).
@@ -399,7 +413,7 @@ def _decide_fused(fused: bool | None, S_global: int, S_local: int, Dh: int):
                                    on_tpu=not interpret) == "pallas"
     if not fused:
         return False, 0, 0, interpret
-    blocks = _fused_blocks(S_local, Dh)
+    blocks = _fused_blocks(S_local, Dh, itemsize)
     if blocks is None:
         return False, 0, 0, interpret
     return True, blocks[0], blocks[1], interpret
@@ -434,7 +448,8 @@ def ring_attention(q, k, v, *, causal=True, window=None, mesh=None,
     B, S, H, Dh = q.shape
     if S % m != 0 or k.shape[1] != S:
         return None
-    use_fused, bq, bk, interp = _decide_fused(fused, S, S // m, Dh)
+    use_fused, bq, bk, interp = _decide_fused(fused, S, S // m, Dh,
+                                              q.dtype.itemsize)
     spec = _RingSpec(mesh=mesh, axis=axis, m=m, causal=bool(causal),
                      window=None if window is None else int(window),
                      dspec=data_axes_spec(mesh, B), fused=use_fused,

@@ -29,7 +29,8 @@ from typing import Any, Callable, Sequence
 import jax
 
 __all__ = [
-    "make_mesh", "set_mesh", "get_abstract_mesh", "shard_map",
+    "make_mesh", "set_mesh", "use_abstract_mesh", "get_abstract_mesh",
+    "shard_map",
     "pcast", "vma", "match_vma",
     "Element", "element_block_spec", "prefetch_scalar_grid_spec",
     "tpu_compiler_params", "memory_stats", "tpu_chips_attached",
@@ -51,6 +52,7 @@ def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
 
 
 set_mesh = jax.set_mesh
+use_abstract_mesh = jax.sharding.use_abstract_mesh
 get_abstract_mesh = jax.sharding.get_abstract_mesh
 shard_map = jax.shard_map
 

@@ -381,3 +381,38 @@ for B, S, H, Hkv, Dh, causal, window in cases:
     print("ok", B, S, H, Hkv, causal, window)
 print("fused ring hop matches einsum fold")
 """)
+
+
+def test_on_mesh_train_step_carries_scopes_in_hlo_metadata():
+    """A bundle placed ``on_mesh`` of a (1, 4) host mesh, the ring forced
+    (1 x 4096 tokens, 1024-token shards): the compiled step's op metadata
+    (what a profiler trace shows as ``tf_op``) names the training layer's
+    scopes and the ring's ``ring_hop``, in the forward and the backward
+    pass."""
+    _run("""
+import os, re
+os.environ["REPRO_RING_ATTN"] = "ring"
+import jax, jax.numpy as jnp
+from repro.configs import get_bundle
+from repro.launch.mesh import make_host_mesh
+from repro.optim import adamw_init
+from repro.training import TrainHyper, make_train_step
+bundle = get_bundle("qwen3-4b", smoke=True).on_mesh(make_host_mesh(4))
+params = jax.jit(bundle.init_params,
+                 out_shardings=bundle.param_shardings())(jax.random.PRNGKey(0))
+opt = jax.jit(adamw_init)(params)
+tok = jnp.zeros((1, 4096), jnp.int32)     # over attention's full_threshold
+step = jax.jit(make_train_step(bundle.forward, TrainHyper()))
+hlo = step.lower(params, opt, {"tokens": tok, "labels": tok}).compile().as_text()
+names = re.findall(r'op_name="([^"]*)"', hlo)
+words = set()
+for n in names:
+    for part in n.split("/")[:-1]:
+        words.update(re.findall(r"[A-Za-z_][\\w.\\-]*", part))
+want = {"qkv", "attention", "mlp", "lm_head", "loss", "adamw", "ring_hop"}
+assert want <= words, want - words
+hops = [n for n in names if "/ring_hop/" in n]
+assert any(n.startswith("jit(") and "transpose(" not in n for n in hops), hops[:4]
+assert any("transpose(" in n for n in hops), hops[:4]
+print("scopes ok", len(hops))
+""", devices=4)

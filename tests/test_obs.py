@@ -712,3 +712,21 @@ def test_mamba2_train_step_carries_its_scopes():
                                                  "labels": tok}))
     assert {"in_proj", "conv", "ssd", "gate_norm", "out_proj", "lm_head",
             "loss", "adamw"} <= scopes
+
+
+def test_transformer_train_step_carries_its_scopes():
+    """The transformer's training layer names its parts as ``paged_step``
+    does, and the step adds ``lm_head``, ``loss`` and ``adamw``."""
+    import jax
+    import jax.numpy as jnp
+    from repro import training
+    from repro.configs import get_bundle
+    from repro.optim import adamw_init
+    b = get_bundle("qwen3-4b", smoke=True)
+    params = b.abstract_params()
+    opt = jax.eval_shape(adamw_init, params)
+    tok = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    step = jax.jit(training.make_train_step(b.forward, training.TrainHyper()))
+    scopes = _scopes_in(step.lower(params, opt, {"tokens": tok,
+                                                 "labels": tok}))
+    assert {"qkv", "attention", "mlp", "lm_head", "loss", "adamw"} <= scopes
