@@ -192,15 +192,23 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                        page_table: jax.Array, lengths: jax.Array,
                        k_scale: jax.Array | None = None,
-                       v_scale: jax.Array | None = None) -> jax.Array:
-    """Paged decode: q (B, H, D) one token; pools (P, page, Hkv, D);
-    page_table (B, max_pages) physical page ids; lengths (B,) valid tokens;
-    optional int8-pool scales (P, page, Hkv).  Returns (B, H, D).
+                       v_scale: jax.Array | None = None,
+                       layer: jax.Array | None = None) -> jax.Array:
+    """Paged decode: q (B, H, D) one token; pools (L, P, page, Hkv, D) of
+    all layers with ``layer`` the one attended, or one layer's (P, page,
+    Hkv, D) with ``layer`` None; page_table (B, max_pages) physical page
+    ids; lengths (B,) valid tokens; optional int8-pool scales (L, P, page,
+    Hkv) or (P, page, Hkv).  Returns (B, H, D).
 
     The kernel reads the pools, table and lengths as they are stored; the
     pages each grid step copies come from the page's bytes."""
+    if layer is None:                  # one layer's pool: L = 1, l = 0
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+        layer = 0
     B, H, Dh = q.shape
-    P, page_size, Hkv, _ = k_pages.shape
+    _, P, page_size, Hkv, _ = k_pages.shape
     MP = int(page_table.shape[1])
     ppb = _paged_attention.pages_per_block(
         page_size, Hkv, Dh, k_pages.dtype.itemsize, MP)
@@ -210,5 +218,5 @@ def paged_flash_decode(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      max_pages=MP, pages_per_block=ppb)
     return _paged_attention.paged_flash_decode_pallas(
         q, k_pages, v_pages, page_table.astype(jnp.int32),
-        lengths.astype(jnp.int32), k_scale, v_scale, pages_per_block=ppb,
-        interpret=_interpret())
+        lengths.astype(jnp.int32), layer, k_scale, v_scale,
+        pages_per_block=ppb, interpret=_interpret())

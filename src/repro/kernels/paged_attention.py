@@ -15,11 +15,12 @@ attends all H query heads of slot b against one block of pages of all Hkv
 kv heads, a static loop over the heads, while the online-softmax state
 ``(m, l, acc)`` of the slot's H rows stays in VMEM scratch across the
 block axis.  The pool is read in place, in the layout the serving step
-stores it, ``(P, page, Hkv, Dh)``: each page of the block is one operand
-whose index map is the table entry, so one page of every kv head is one
-contiguous copy and nothing the size of the pool or the table is
-transposed or repeated per call.  The pipeline double-buffers those
-copies across grid steps.
+stores it, ``(L, P, page, Hkv, Dh)`` for all L layers: the layer is a
+third scalar-prefetch operand, and each page of the block is one operand
+whose index map is (layer, table entry), so one page of every kv head is
+one contiguous copy and nothing the size of a layer's pool or the table
+is sliced, transposed or repeated per call.  The pipeline double-buffers
+those copies across grid steps.
 
 Bound by length: a block whose first position is at or past the slot's
 length does no compute, and its index maps name the block the pipeline
@@ -39,7 +40,7 @@ gets its block from the same rule.
 
 The int8 path keeps the pool quantized in HBM and dequantizes one block
 at a time inside the kernel, so quantized serving never materializes an
-f32 cache.  Scales ride in their stored ``(P, page, Hkv)`` layout, one
+f32 cache.  Scales ride in their stored ``(L, P, page, Hkv)`` layout, one
 operand per page beside the page's K or V; a head's scales scale the rows
 of its K and V tiles, which equals the dequantized products.
 """
@@ -70,8 +71,9 @@ def pages_per_block(page_size: int, n_kv_heads: int, head_dim: int,
     return max(1, min(BLOCK_BYTES // page_bytes, max_pages))
 
 
-def _paged_decode_kernel(pt_ref, len_ref, q_ref, *refs, scale: float,
-                         ppb: int, quantized: bool):
+def _paged_decode_kernel(pt_ref, len_ref, layer_ref, q_ref, *refs,
+                         scale: float, ppb: int, quantized: bool):
+    del layer_ref                       # read by the index maps only
     n = 4 if quantized else 2
     k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
     ks_refs = vs_refs = None
@@ -150,21 +152,21 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, *refs, scale: float,
 
 def paged_flash_decode_pallas(q: jax.Array, k_pages: jax.Array,
                               v_pages: jax.Array, page_table: jax.Array,
-                              lengths: jax.Array,
+                              lengths: jax.Array, layer: jax.Array,
                               k_scale: jax.Array | None = None,
                               v_scale: jax.Array | None = None, *,
                               pages_per_block: int,
                               scale: float | None = None,
                               interpret: bool = False) -> jax.Array:
     """q: (B, H, D) one token per slot, query heads grouped by kv head;
-    k_pages/v_pages: (P, page_size, Hkv, D) global pools as stored;
-    page_table: (B, max_pages) physical ids (page 0 = trash, masked by
-    length); lengths: (B,) valid cached tokens (>= 1: page 0 of every live
-    slot covers position 0, so a slot's first block is never fully
-    masked).  Scales (int8 pools): (P, page_size, Hkv) f32.  Returns
-    (B, H, D)."""
+    k_pages/v_pages: (L, P, page_size, Hkv, D) global pools of all layers
+    as stored; page_table: (B, max_pages) physical ids (page 0 = trash,
+    masked by length); lengths: (B,) valid cached tokens (>= 1: page 0 of
+    every live slot covers position 0, so a slot's first block is never
+    fully masked); layer: int32 scalar, the layer attended.  Scales (int8
+    pools): (L, P, page_size, Hkv) f32.  Returns (B, H, D)."""
     B, H, Dh = q.shape
-    P, page_size, Hkv, _ = k_pages.shape
+    _, P, page_size, Hkv, _ = k_pages.shape
     assert H % Hkv == 0, (H, Hkv)
     MP = page_table.shape[1]
     ppb = pages_per_block
@@ -174,33 +176,36 @@ def paged_flash_decode_pallas(q: jax.Array, k_pages: jax.Array,
     scale = scale if scale is not None else 1.0 / math.sqrt(Dh)
 
     def page_of(j, n_dims):
-        """Index map of the block's j-th page.  A block past the slot's
-        length names the next slot's first block instead (the last slot:
-        its own last live block), so the pipeline copies nothing for it
-        and the next slot's first copy overlaps this slot's last compute.
-        Columns past the view re-read the view's last page (masked)."""
-        def index(b, i, pt, ln):
+        """Index map of the block's j-th page of the layer.  A block past
+        the slot's length names the next slot's first block instead (the
+        last slot: its own last live block), so the pipeline copies
+        nothing for it and the next slot's first copy overlaps this slot's
+        last compute.  Columns past the view re-read the view's last page
+        (masked)."""
+        def index(b, i, pt, ln, lyr):
             # lax, not jnp: these run once per page operand at trace time
             last = lax.div(lax.max(lax.sub(ln[b], 1), 0), block_tokens)
             ahead = lax.bitwise_and(lax.gt(i, last), lax.lt(b, B - 1))
             row = lax.select(ahead, lax.add(b, 1), b)
             blk = lax.select(ahead, 0, lax.min(i, last))
             col = lax.min(lax.add(lax.mul(blk, ppb), j), MP - 1)
-            return (pt[row, col],) + (0,) * (n_dims - 1)
+            return (lyr[0], pt[row, col]) + (0,) * (n_dims - 2)
         return index
 
     G = H // Hkv
-    q_spec = pl.BlockSpec((1, Hkv, G, Dh), lambda b, i, pt, ln: (b, 0, 0, 0))
+    q_spec = pl.BlockSpec((1, Hkv, G, Dh),
+                          lambda b, i, pt, ln, lyr: (b, 0, 0, 0))
     in_specs = [q_spec]
-    in_specs += [pl.BlockSpec((1, page_size, Hkv, Dh), page_of(j, 4))
+    # the layer dim is squeezed: the kernel sees (1, page, Hkv, Dh) pages
+    in_specs += [pl.BlockSpec((None, 1, page_size, Hkv, Dh), page_of(j, 5))
                  for j in range(ppb)] * 2
     operands = [q.reshape(B, Hkv, G, Dh)] + [k_pages] * ppb + [v_pages] * ppb
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, Hkv), page_of(j, 3))
+        in_specs += [pl.BlockSpec((None, 1, page_size, Hkv), page_of(j, 4))
                      for j in range(ppb)] * 2
         operands += [k_scale] * ppb + [v_scale] * ppb
     grid_spec = compat.prefetch_scalar_grid_spec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, n_blocks),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -217,4 +222,5 @@ def paged_flash_decode_pallas(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q.dtype),
         interpret=interpret,
-    )(page_table, lengths, *operands).reshape(B, H, Dh)
+    )(page_table, lengths, jnp.reshape(layer, (1,)).astype(jnp.int32),
+      *operands).reshape(B, H, Dh)

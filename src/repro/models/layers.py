@@ -501,24 +501,27 @@ def decode_attention(q, k_cache, v_cache, lengths, k_scale=None,
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
-                           k_scale=None, v_scale=None, *, impl="xla"):
+                           k_scale=None, v_scale=None, *, layer=None,
+                           impl="xla"):
     """Attention of freshly written tokens against a paged KV pool.
 
     q: (B, T, H, Dh) — token t of row b sits at position ``lengths[b] + t``
     and its K/V have already been scattered into the pool, so it attends
-    every position <= its own.  k_pages/v_pages: (P, page, Hkv, Dh) ONE
-    layer's global pool; page_table: (B, max_pages) physical ids (0 =
-    trash, always masked by the position bound); lengths: (B,) tokens
-    cached BEFORE this step's writes.  Scales (int8 pools): (P, page,
-    Hkv) f32.
+    every position <= its own.  k_pages/v_pages: (L, P, page, Hkv, Dh) the
+    global pools of all layers, of which ``layer`` (an int32 scalar) is
+    attended, or ONE layer's (P, page, Hkv, Dh) with ``layer`` None;
+    page_table: (B, max_pages) physical ids (0 = trash, always masked by
+    the position bound); lengths: (B,) tokens cached BEFORE this step's
+    writes.  Scales (int8 pools): (L, P, page, Hkv) or (P, page, Hkv) f32.
 
     ``impl='pallas'`` (T == 1 only) dispatches to the paged flash-decode
     kernel, which chases the page table inside the grid — no gathered
     contiguous cache ever materializes.  The XLA path gathers the mapped
-    pages (bounded by the page-table slice the engine passes, NOT by
-    max_len) and runs a masked softmax; it is the CPU/equivalence path."""
+    pages of the layer with one index (bounded by the page-table slice the
+    engine passes, NOT by max_len; no layer's pool is sliced out) and runs
+    a masked softmax; it is the CPU/equivalence path."""
     B, T, H, Dh = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    P, page, Hkv, _ = k_pages.shape[-4:]
     if impl in (None, "auto"):
         # decode q is one token; the flash policy's min-seq threshold is a
         # prefill knob, so auto here is purely a backend question
@@ -530,16 +533,18 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     if impl == "pallas" and T == 1:
         from repro.kernels import ops as kops
         o = kops.paged_flash_decode(q[:, 0], k_pages, v_pages, page_table,
-                                    lengths + 1, k_scale, v_scale)
+                                    lengths + 1, k_scale, v_scale, layer)
         return o[:, None].astype(q.dtype)
     G = H // Hkv
     S = page_table.shape[1] * page
     scale = 1.0 / math.sqrt(Dh)
 
+    idx = page_table if layer is None else (layer, page_table)
+
     def gather(pages, scales):
-        x = pages[page_table].astype(jnp.float32)  # (B, MP, page, Hkv, D)
+        x = pages[idx].astype(jnp.float32)         # (B, MP, page, Hkv, D)
         if scales is not None:
-            x = x * scales[page_table][..., None]
+            x = x * scales[idx][..., None]
         return x.reshape(B, S, Hkv, Dh)
 
     k = gather(k_pages, k_scale)

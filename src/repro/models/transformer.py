@@ -357,36 +357,31 @@ def paged_step(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         return jax.lax.with_sharding_constraint(
             x, jax.sharding.PartitionSpec(*([None] * x.ndim)))
 
-    def write(pages, new):
-        # (P, page, ...) scattered at per-token (phys, off) pairs; rows of
-        # one slot never collide (consecutive positions), distinct slots
-        # own distinct pages, and all invalid tokens land on trash page 0.
-        return pages.at[phys, off].set(new.astype(pages.dtype))
+    def write(pages, l, new):
+        # (L, P, page, ...) scattered at per-token (l, phys, off) in place:
+        # rows of one slot never collide (consecutive positions), distinct
+        # slots own distinct pages, and all invalid tokens land on trash
+        # page 0.
+        return pages.at[l, phys, off].set(new.astype(pages.dtype))
 
-    def body(x, inp):
-        if quantized:
-            lp, kc, vc, ksc, vsc = inp
-        else:
-            lp, kc, vc = inp
-            ksc = vsc = None
+    def body(carry, inp):
+        x, pool = carry
+        lp, l = inp
         with jax.named_scope("qkv"):
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
             q, k, v = _qkv(cfg, lp, h, positions)
             q, k, v = replicate(q), replicate(k), replicate(v)
         with jax.named_scope("attention"):
             if quantized:
-                kq, ks_ = quantize_kv(k)
-                vq, vs_ = quantize_kv(v)
-                kc, vc = write(kc, kq), write(vc, vq)
-                ksc, vsc = write(ksc, ks_), write(vsc, vs_)
-                o = paged_decode_attention(q, kc, vc, page_table, lengths,
-                                           ksc, vsc, impl=impl)
-                out_pool = (kc, vc, ksc, vsc)
+                (kq, ks_), (vq, vs_) = quantize_kv(k), quantize_kv(v)
+                new = {"k": kq, "v": vq, "k_scale": ks_, "v_scale": vs_}
             else:
-                kc, vc = write(kc, k), write(vc, v)
-                o = paged_decode_attention(q, kc, vc, page_table, lengths,
-                                           impl=impl)
-                out_pool = (kc, vc)
+                new = {"k": k, "v": v}
+            pool = {n: write(pool[n], l, new[n]) for n in pool}
+            o = paged_decode_attention(
+                q, pool["k"], pool["v"], page_table, lengths,
+                pool.get("k_scale"), pool.get("v_scale"), layer=l,
+                impl=impl)
             x = x + replicate(o).reshape(B, T, -1) @ lp["wo"]
         with jax.named_scope("mlp"):
             h = rms_norm(x, lp["ln2"], cfg.norm_eps)
@@ -397,17 +392,15 @@ def paged_step(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         # the residual carry stays replicated too: serving activations are
         # small, and this keeps GSPMD from threading pool-derived layouts
         # through the layer scan
-        return replicate(x + mo), out_pool
+        return (replicate(x + mo), pool), None
 
-    if quantized:
-        xs = (params["layers"], pool["k"], pool["v"], pool["k_scale"],
-              pool["v_scale"])
-        x, (ks, vs, kss, vss) = jax.lax.scan(body, x, xs)
-        new_pool = {"k": ks, "v": vs, "k_scale": kss, "v_scale": vss}
-    else:
-        x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], pool["k"],
-                                             pool["v"]))
-        new_pool = {"k": ks, "v": vs}
+    # the pool rides in the carry, not in xs/ys: each layer updates the
+    # stacked (L, P, ...) arrays in place and the kernel reads layer l
+    # through its index maps, so no layer's pool is sliced out or written
+    # back (with the pool donated, the step never copies it)
+    L = pool["k"].shape[0]
+    (x, new_pool), _ = jax.lax.scan(
+        body, (x, pool), (params["layers"], jnp.arange(L)))
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)
         logits = x @ params["lm_head"]

@@ -53,6 +53,7 @@ engine itself is host-side control logic and is exercised on CPU in tests.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Iterator
 
 import jax
@@ -134,15 +135,18 @@ def _pow2_at_least(n: int, lo: int = 1) -> int:
 # would give each engine its own compile cache, so benchmarks/tests that
 # build fresh engines over the same bundle would re-trace identical
 # shapes).  ``step`` is the bundle's paged_step, static so its identity
-# keys the cache.
-@jax.jit
+# keys the cache.  Each donates the pool it is given, so a step or a page
+# copy updates the pool in place instead of writing a second one; the
+# engine rebinds ``self.pool`` to the result and nothing else may hold the
+# old arrays.
+@functools.partial(jax.jit, donate_argnums=0)
 def _copy_pool_page(pool, src, dst):
     """COW: copy one physical page (all layers, K+V+scales).  The page
     ids ride as traced scalars so every copy reuses one trace."""
     return {k: v.at[:, dst].set(v[:, src]) for k, v in pool.items()}
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def _write_pool_page(pool, dst, vals):
     """Land a MIGRATED page's payload (all layers, K+V+scales) at
     physical page ``dst`` — the receive half of fleet page migration.
@@ -162,7 +166,7 @@ def _pick_step(step, params, tokens, pool, pt, lens, counts):
     return rows, jnp.argmax(rows, axis=-1), pool
 
 
-_pick_step = jax.jit(_pick_step, static_argnums=0)
+_pick_step = jax.jit(_pick_step, static_argnums=0, donate_argnums=3)
 
 
 class ServingEngine:

@@ -64,20 +64,52 @@ def _kernel_case(Hkv, G, Dh, seed=0, pg=16):
     return q, k, v, jnp.asarray(table), jnp.asarray(KERNEL_LENS), int8
 
 
-def _kernel(ppb, *args):
+def _kernel(ppb, *args, layer=None):
     """The kernel with ``ppb`` pages a block, or through the jitted
-    wrapper (pages per block from the shapes) when ``ppb`` is None."""
+    wrapper (pages per block from the shapes) when ``ppb`` is None.
+    Pools of all layers are read at ``layer``; one layer's pools (layer
+    None) are that layer of a one-layer stack."""
     from repro.kernels import ops
     from repro.kernels import paged_attention as kpaged
     if ppb is None:
-        return ops.paged_flash_decode(*args)
-    return kpaged.paged_flash_decode_pallas(*args, pages_per_block=ppb,
-                                            interpret=True)
+        return ops.paged_flash_decode(*args, layer=layer)
+    if layer is None:
+        q, *pools = args
+        args = [q] + [x[None] if i in (0, 1, 4, 5) else x
+                      for i, x in enumerate(pools)]
+        layer = 0
+    q, k, v, pt, lens, *scales = args
+    return kpaged.paged_flash_decode_pallas(
+        q, k, v, pt, lens, jnp.int32(layer), *scales, pages_per_block=ppb,
+        interpret=True)
 
 
 _KERNEL_CASES = ([pytest.param(None, None, id="pool_setup")]
                  + [pytest.param(s, ppb, id=f"{s}-ppb{ppb or 'auto'}")
                     for s in KERNEL_SHAPES for ppb in (KERNEL_PPB, None)])
+# the case's pools alone, or as the first or last of LAYERS stacked layers
+LAYERS = 3
+_LAYER_CASES = [pytest.param(None, id="one-layer"),
+                pytest.param(0, id="l0"),
+                pytest.param(LAYERS - 1, id="l-last")]
+
+
+def _in_layer(args, layer):
+    """``args`` with its pools (and scales) as layer ``layer`` of LAYERS
+    stacked layers whose others hold noise, so a read of the wrong layer
+    shows; ``layer`` None leaves them one layer's."""
+    if layer is None:
+        return args
+    rng = np.random.default_rng(7)
+
+    def stack(x):
+        noise = [jnp.asarray(rng.uniform(-100, 100, x.shape)).astype(x.dtype)
+                 for _ in range(LAYERS)]
+        noise[layer] = x
+        return jnp.stack(noise)
+
+    return tuple(stack(x) if i in (1, 2, 5, 6) else x
+                 for i, x in enumerate(args))
 
 
 def _case_args(shape, int8=False):
@@ -96,20 +128,22 @@ def _case_args(shape, int8=False):
     return (q, kq, vq, pt, lens, ks, vs) if int8 else (q, k, v, pt, lens)
 
 
+@pytest.mark.parametrize("layer", _LAYER_CASES)
 @pytest.mark.parametrize("shape,ppb", _KERNEL_CASES)
-def test_paged_kernel_matches_ref(shape, ppb):
+def test_paged_kernel_matches_ref(shape, ppb, layer):
     from repro.kernels.ref import paged_decode_ref
     args = _case_args(shape)
-    out = _kernel(ppb, *args)
+    out = _kernel(ppb, *_in_layer(args, layer), layer=layer)
     ref = paged_decode_ref(*args)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
+@pytest.mark.parametrize("layer", _LAYER_CASES)
 @pytest.mark.parametrize("shape,ppb", _KERNEL_CASES)
-def test_paged_kernel_int8_matches_ref(shape, ppb):
+def test_paged_kernel_int8_matches_ref(shape, ppb, layer):
     from repro.kernels.ref import paged_decode_ref
     args = _case_args(shape, int8=True)
-    out = _kernel(ppb, *args)
+    out = _kernel(ppb, *_in_layer(args, layer), layer=layer)
     ref = paged_decode_ref(*args)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
@@ -981,3 +1015,48 @@ def test_engine_fuzz_outcomes_account_for_every_request():
     assert sorted(res) == sorted(rids)
     assert all(len(res[r]) <= 4 for r in rids)
     engine.check_kv()
+
+
+@pytest.mark.parametrize("kv_mode", ["paged", "paged_int8"])
+def test_engine_step_donates_pool(kv_mode):
+    """The step program takes the engine's pool as a donated argument: a
+    tick updates it in place, so the arrays the engine held before the
+    tick are deleted and no second pool is ever live."""
+    from repro.launch.serve import build_engine
+    engine, vocab = build_engine("qwen3-4b", slots=2, max_len=48, max_new=4,
+                                 kv_mode=kv_mode, page_size=8)
+    for p in _prompts(vocab, n=3):
+        engine.submit(p)
+    while engine.pending():
+        before = list(engine.pool.values())
+        engine.step()
+        assert all(x.is_deleted() for x in before)
+        assert not any(x.is_deleted() for x in engine.pool.values())
+    engine.check_kv()
+
+
+def test_pool_page_copies_donate_pool():
+    """Copy-on-write and a migrated page's landing update the pool in
+    place: the pool given is deleted, and only the target page of every
+    layer changes."""
+    from repro.serving import engine as serving_engine
+    rng = np.random.default_rng(5)
+
+    def pool():
+        return {k: jnp.asarray(rng.normal(size=(3, 6, 4, 2, 8)), jnp.float32)
+                for k in ("k", "v")}
+
+    src = pool()
+    want = {k: np.array(v) for k, v in src.items()}   # copies: no view
+    out = serving_engine._copy_pool_page(src, jnp.int32(2), jnp.int32(5))
+    assert all(x.is_deleted() for x in src.values())
+    for k, v in want.items():
+        v[:, 5] = v[:, 2]
+        np.testing.assert_array_equal(np.asarray(out[k]), v)
+    vals = {k: jnp.asarray(rng.normal(size=(3, 4, 2, 8)), jnp.float32)
+            for k in ("k", "v")}
+    landed = serving_engine._write_pool_page(out, jnp.int32(1), vals)
+    assert all(x.is_deleted() for x in out.values())
+    for k, v in want.items():
+        v[:, 1] = np.asarray(vals[k])
+        np.testing.assert_array_equal(np.asarray(landed[k]), v)

@@ -12,6 +12,7 @@ process at a time may load the TPU library, so nothing here touches it
 while the module is imported.
 """
 import dataclasses
+import functools
 import math
 import os
 import re
@@ -94,22 +95,25 @@ def test_flash_forward_backward_compiles(one_chip):
 @pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
 def test_paged_decode_compiles(one_chip, kv_dtype):
-    """The pool as the serving step stores it, (P, page, Hkv, Dh), and
-    per-slot (B, MP) tables, at a view of 64 pages and at docqa's 394,
-    which is no multiple of the block."""
-    slots, page = 8, 16
+    """The pool as the serving step stores it, (L, P, page, Hkv, Dh) for
+    qwen3-4b's 36 layers with the layer a scalar operand, and per-slot
+    (B, MP) tables, at a view of 64 pages and at docqa's 394, which is no
+    multiple of the block."""
+    slots, page, n_layers = 8, 16, 36
     for per_slot in (64, 394):
         n_pages = slots * per_slot + 1
         ppb = kpaged.pages_per_block(page, HKV, DH,
                                      jnp.dtype(kv_dtype).itemsize, per_slot)
         assert per_slot == 64 or per_slot % ppb
         q = _struct((slots, H, DH), jnp.bfloat16, one_chip)
-        pool = _struct((n_pages, page, HKV, DH), kv_dtype, one_chip)
+        pool = _struct((n_layers, n_pages, page, HKV, DH), kv_dtype, one_chip)
         table = _struct((slots, per_slot), jnp.int32, one_chip)
         lengths = _struct((slots,), jnp.int32, one_chip)
-        args = [q, pool, pool, table, lengths]
+        layer = _struct((), jnp.int32, one_chip)
+        args = [q, pool, pool, table, lengths, layer]
         if kv_dtype == jnp.int8:
-            scales = _struct((n_pages, page, HKV), jnp.float32, one_chip)
+            scales = _struct((n_layers, n_pages, page, HKV), jnp.float32,
+                             one_chip)
             args += [scales, scales]
 
         def decode(*a, ppb=ppb):
@@ -159,6 +163,70 @@ def test_qwen3_4b_paged_step_layer_compiles(one_chip, monkeypatch):
     relaid = [m.group(1) for m in re.finditer(
         r"= \w+(\[[\d,]+\])\{[^}]*\} (?:copy|transpose)\(", text)]
     assert not pools & set(relaid), relaid
+
+
+@pytest.mark.parametrize("kv_mode,T", [("paged", 1), ("paged_int8", 1),
+                                       ("paged", 64)],
+                         ids=["decode", "decode-int8", "prefill-chunk"])
+def test_qwen3_4b_paged_step_scan_updates_pool_in_place(one_chip,
+                                                        monkeypatch, kv_mode,
+                                                        T):
+    """The engine's step program, ``serving.engine._pick_step`` with the
+    pool donated, over a two-layer scan of full-width qwen3-4b layers:
+    each layer writes the stacked pool in place and the kernel reads its
+    layer there, so no op copies, transposes, slices or rewrites a whole
+    layer's pool or the whole pool, and every pool array is an output
+    aliased to its donated input."""
+    from repro.configs import get_bundle
+    from repro.kernels import ops
+    from repro.serving import engine
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    bundle = get_bundle("qwen3-4b", smoke=False)
+    cfg = dataclasses.replace(bundle.cfg, n_layers=2, attn_impl="pallas")
+    slots, page, per_slot = 8, 16, 64
+    n_pages = slots * per_slot + 1
+
+    def place(tree):
+        return jax.tree.map(
+            lambda s: _struct(s.shape, s.dtype, one_chip), tree)
+
+    params = place(jax.eval_shape(
+        lambda k: bundle.family.init_params(cfg, k),
+        jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    kv_dtype = jnp.int8 if kv_mode == "paged_int8" else None
+    pool = place(jax.eval_shape(
+        lambda: bundle.family.init_paged_pool(cfg, n_pages, page,
+                                              kv_dtype=kv_dtype)))
+    tokens = _struct((slots, T), jnp.int32, one_chip)
+    table = _struct((slots, per_slot), jnp.int32, one_chip)
+    lengths = _struct((slots,), jnp.int32, one_chip)
+    step = functools.partial(bundle.family.paged_step, cfg)
+    text = engine._pick_step.lower(step, params, tokens, pool, table,
+                                   lengths, lengths).compile().as_text()
+    if T == 1:
+        assert re.search(r"%paged_flash_decode(\.\d+)? = \S+ custom-call\(",
+                         text)
+    # a whole layer's K/V pool or the whole pool, in any order of its dims
+    # (the int8 path's scales, whose (page, Hkv) minor dims the device
+    # lays out page-minor, are relaid for the kernel once a step)
+    pools = set()
+    for x in (pool["k"], pool["v"]):
+        pools |= {tuple(sorted(x.shape)), tuple(sorted(x.shape[1:]))}
+    moved = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\{[^}]*\} "
+                         r"(copy|transpose|dynamic-slice|"
+                         r"dynamic-update-slice)\(", text):
+        dims = tuple(sorted(int(d) for d in m.group(1).split(",")
+                            if d and d != "1"))
+        if dims in pools:
+            moved.append(m.group(0))
+    assert not moved, moved
+    # the pool's parameters follow the params' leaves and the tokens
+    first = len(jax.tree.leaves(params)) + 1
+    alias = re.search(r"input_output_alias=\{ (.*?) \}", text)
+    assert alias, "the step aliases no output to its donated pool"
+    aliased = {int(i) for i in re.findall(r"\((\d+), \{\}", alias.group(1))}
+    assert set(range(first, first + len(pool))) <= aliased, alias.group(1)
 
 
 def test_ring_fused_hop_compiles_on_2x2(topo, monkeypatch):
