@@ -1,7 +1,7 @@
-"""Config registry: ``get_bundle(arch_id, smoke=False)`` + the shape table."""
+"""Config registry: ``get_bundle(arch_id, smoke=False)``."""
 from __future__ import annotations
 
-from .base import (RING_MODES, SHAPES, ArchBundle, RingAttnPolicy,
+from .base import (RING_MODES, ArchBundle, RingAttnPolicy,
                    decide_ring, ring_attn_policy)
 from . import (granite_moe_3b, internvl2_26b, mamba2_370m, olmoe_1b_7b,
                qwen1_5_32b, qwen2_5_14b, qwen3_4b, recurrentgemma_9b,
@@ -20,5 +20,5 @@ def get_bundle(arch_id: str, smoke: bool = False) -> ArchBundle:
     return mod.smoke_bundle() if smoke else mod.full_bundle()
 
 
-__all__ = ["SHAPES", "ArchBundle", "REGISTRY", "ARCH_IDS", "get_bundle",
+__all__ = ["ArchBundle", "REGISTRY", "ARCH_IDS", "get_bundle",
            "RING_MODES", "RingAttnPolicy", "decide_ring", "ring_attn_policy"]

@@ -1,38 +1,22 @@
 """Architecture bundles: one uniform interface over all model families.
 
 A bundle wires a model family (transformer / mamba2 / recurrentgemma /
-whisper) to the launcher, dry-run, trainer and server:
+whisper) to the launchers, trainer and server:
 
   * ``abstract_params()``        — ShapeDtypeStruct tree (no allocation)
   * ``forward(params, batch)``   — training forward, (logits, aux)
   * ``prefill/decode_step``      — serving steps
-  * ``input_specs(shape)``       — ShapeDtypeStruct batch for a named shape
-  * ``step_kind(shape)``         — which step function the shape lowers
-  * ``supports(shape)``          — long_500k only for sub-quadratic archs
-
-SHAPES (assignment): train_4k (4096 x 256, train_step), prefill_32k
-(32768 x 32, prefill), decode_32k (one token, 32k cache, batch 128),
-long_500k (one token, 524288 context, batch 1; SSM/hybrid only).
+  * ``paged_step``               — one chunked-prefill + decode step over
+    a paged KV pool
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 import os
 from typing import Any, Callable
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
-
-SHAPES = {
-    "train_4k": dict(seq_len=4096, global_batch=256, kind="train"),
-    "prefill_32k": dict(seq_len=32768, global_batch=32, kind="prefill"),
-    "decode_32k": dict(seq_len=32768, global_batch=128, kind="decode"),
-    "long_500k": dict(seq_len=524288, global_batch=1, kind="decode"),
-}
-
 
 # ---------------------------------------------------------------------------
 # Context-parallel ring-attention policy (§Perf B6)
@@ -168,8 +152,6 @@ class ArchBundle:
     kind: str                   # dense | moe | vlm | ssm | audio | hybrid
     cfg: Any
     family: Any                 # model module
-    sub_quadratic: bool = False
-    kv_dtype_decode: Any = None  # e.g. jnp.int8 for big dense decode
     extras: dict = dataclasses.field(default_factory=dict)
     # the device mesh the training forward shards over (``on_mesh``);
     # None: whatever mesh the caller has entered, if any
@@ -285,93 +267,3 @@ class ArchBundle:
         declared = getattr(self.family, "CACHE_BATCH_AXES", {})
         return {k: declared.get(k, 0 if jnp.ndim(v) == 1 else 1)
                 for k, v in cache.items()}
-
-    def min_hbm_bytes(self, shape_name: str) -> int:
-        """Theoretical HBM traffic floor for one step of this shape.
-
-        train:   params read fwd+bwd + grads w+r + Adam mu/nu r+w (f32) +
-                 layer-boundary activations w+r (bf16)
-        decode:  full params read once + KV cache read (+ small writes)
-        prefill: params read + activations written + cache written
-        """
-        sh = SHAPES[shape_name]
-        S, B = sh["seq_len"], sh["global_batch"]
-        kind = sh["kind"]
-        n = self.param_count()
-        n_active = self.active_param_count()
-        D = self.cfg.d_model
-        L = getattr(self.cfg, "n_layers", 1)
-        if kind == "train":
-            act = 2 * 2 * L * B * S * D           # save+read, bf16
-            return int(3 * 2 * n + (4 + 16) * n + 2 * n_active * 0 + act)
-        # serving floors
-        cache = jax.eval_shape(functools.partial(self.init_cache, B, S))
-        cache_bytes = sum(
-            int(np.prod(l.shape)) * l.dtype.itemsize
-            for l in jax.tree.leaves(cache))
-        if kind == "decode":
-            return int(2 * n + cache_bytes)
-        act = 2 * B * S * D * L
-        return int(2 * n + cache_bytes + act)
-
-    # -- shapes ----------------------------------------------------------------
-    def supports(self, shape_name: str) -> tuple[bool, str]:
-        if shape_name == "long_500k" and not self.sub_quadratic:
-            return False, ("full quadratic attention: 512k decode cache "
-                           "infeasible; run on SSM/hybrid archs only "
-                           "(see DESIGN.md §Arch-applicability)")
-        return True, ""
-
-    def step_kind(self, shape_name: str) -> str:
-        return SHAPES[shape_name]["kind"]
-
-    def input_specs(self, shape_name: str) -> dict:
-        """ShapeDtypeStruct stand-ins for every input of the step function."""
-        sh = SHAPES[shape_name]
-        S, B = sh["seq_len"], sh["global_batch"]
-        kind = sh["kind"]
-        i32 = jnp.int32
-        D = self.cfg.d_model
-
-        if kind == "train":
-            if self.kind == "vlm":
-                P = self.cfg.vision_tokens
-                return {
-                    "tokens": jax.ShapeDtypeStruct((B, S - P), i32),
-                    "labels": jax.ShapeDtypeStruct((B, S - P), i32),
-                    "vision": jax.ShapeDtypeStruct((B, P, D), self.cfg.dtype),
-                }
-            if self.kind == "audio":
-                return {
-                    "tokens": jax.ShapeDtypeStruct((B, S), i32),
-                    "labels": jax.ShapeDtypeStruct((B, S), i32),
-                    "frames": jax.ShapeDtypeStruct(
-                        (B, self.cfg.n_audio_ctx, D), self.cfg.dtype),
-                }
-            return {
-                "tokens": jax.ShapeDtypeStruct((B, S), i32),
-                "labels": jax.ShapeDtypeStruct((B, S), i32),
-            }
-
-        if kind == "prefill":
-            specs = {"tokens": jax.ShapeDtypeStruct((B, S), i32)}
-            if self.kind == "vlm":
-                P = self.cfg.vision_tokens
-                specs["tokens"] = jax.ShapeDtypeStruct((B, S - P), i32)
-                specs["vision"] = jax.ShapeDtypeStruct((B, P, D),
-                                                       self.cfg.dtype)
-            if self.kind == "audio":
-                specs["frames"] = jax.ShapeDtypeStruct(
-                    (B, self.cfg.n_audio_ctx, D), self.cfg.dtype)
-            specs["cache"] = jax.eval_shape(
-                functools.partial(self.init_cache, B, S))
-            return specs
-
-        # decode: one token against a cache of S
-        kv_dt = self.kv_dtype_decode if shape_name == "decode_32k" else None
-        specs = {
-            "tokens": jax.ShapeDtypeStruct((B, 1), i32),
-            "cache": jax.eval_shape(
-                functools.partial(self.init_cache, B, S, kv_dtype=kv_dt)),
-        }
-        return specs

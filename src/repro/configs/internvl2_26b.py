@@ -1,7 +1,7 @@
 """internvl2-26b [vlm]: InternLM2 backbone 48L d=6144 48H (GQA kv=8)
-d_ff=16384 vocab=92672. The InternViT frontend is a STUB per the assignment:
-input_specs supplies precomputed patch embeddings (B, 256, D) that prefix
-the text sequence. [arXiv:2404.16821; hf]"""
+d_ff=16384 vocab=92672. The InternViT frontend is a STUB: the
+batch's "vision" entry carries precomputed patch embeddings (B, 256, D)
+that prefix the text sequence. [arXiv:2404.16821; hf]"""
 import jax.numpy as jnp
 
 from repro.models import TransformerConfig, transformer

@@ -1,7 +1,7 @@
 """recurrentgemma-9b [hybrid]: 38L d=4096 16H (MQA kv=1, head_dim 256)
 d_ff=12288 — RG-LRU + local attention (window 2048), pattern 1 attn : 2 rec.
-vocab=256000. Sub-quadratic: runs long_500k (decode state is O(1) + a
-window-bounded attention cache). [arXiv:2402.19427; unverified]"""
+vocab=256000. Decode state is O(1) + a window-bounded attention cache.
+[arXiv:2402.19427; unverified]"""
 import jax.numpy as jnp
 
 from repro.models import RGConfig, recurrentgemma
@@ -13,13 +13,11 @@ ARCH_ID = "recurrentgemma-9b"
 def full_bundle() -> ArchBundle:
     cfg = RGConfig(name=ARCH_ID, n_layers=38, d_model=4096, n_heads=16,
                    n_kv_heads=1, d_ff=12288, vocab=256000, window=2048)
-    return ArchBundle(ARCH_ID, "hybrid", cfg, recurrentgemma,
-                      sub_quadratic=True)
+    return ArchBundle(ARCH_ID, "hybrid", cfg, recurrentgemma)
 
 
 def smoke_bundle() -> ArchBundle:
     cfg = RGConfig(name=ARCH_ID + "-smoke", n_layers=5, d_model=64,
                    n_heads=4, n_kv_heads=1, d_ff=128, vocab=256, window=64,
                    dtype=jnp.float32)
-    return ArchBundle(ARCH_ID, "hybrid", cfg, recurrentgemma,
-                      sub_quadratic=True)
+    return ArchBundle(ARCH_ID, "hybrid", cfg, recurrentgemma)

@@ -1,6 +1,6 @@
 """whisper-medium [audio]: 24L (enc) + 24L (dec) d=1024 16H (MHA) d_ff=4096
-vocab=51968 — enc-dec; the conv frontend is a STUB (input_specs provides
-precomputed frame embeddings). [arXiv:2212.04356; unverified]"""
+vocab=51968 — enc-dec; the conv frontend is a STUB (the batch's "frames"
+entry carries precomputed frame embeddings). [arXiv:2212.04356; unverified]"""
 import jax.numpy as jnp
 
 from repro.models import WhisperConfig, whisper

@@ -2,7 +2,7 @@
 planning (paper §II-B, Fig. 2 — fast path).
 
 Everything this repo derives from the paper — the Table III traffic numbers,
-the Fig. 3/4 rooflines, the dry-run table, and the Pallas ``plan_kernel``
+the Fig. 3/4 rooflines, and the Pallas ``plan_kernel``
 block shapes — funnels through two brute-force searches: the §II-B tile
 search (``core.tiling.search_tiles``) and the Fig. 2 grid-order search
 (``core.exchange.order_grid_for_sharing``).  The reference implementations
@@ -28,17 +28,13 @@ This module replaces those hot paths with three composable layers:
    hanging off it.  Conv-style 6-dim ops never touch the full cartesian
    product.  Per-dim candidate values are pre-capped the same way.
 
-3. **Memoization** (``_memo`` + optional on-disk cache).  Results are
+3. **Memoization** (``_memo``).  Results are
    keyed by a *structural* op signature (dim sizes/kinds, affine coeffs,
    bytes-per-elem, macs-per-point — NOT the op name) plus the BufferSpec /
    caps / mesh arguments, in a process-wide LRU.  ``search_tiles``,
    ``plan_mesh_exchange``, ``order_grid_for_sharing`` and (transitively)
    ``pallas_bridge.plan_kernel`` all share it, so the simulator's repeated
-   searches across PE sweeps are free after the first.  Setting
-   ``REPRO_SCHED_DISK_CACHE=1`` additionally persists entries as JSON under
-   ``.cache/repro_scheduler/`` (override the location with
-   ``REPRO_CACHE_DIR``) so repeated benchmark runs start warm; delete the
-   directory or call ``clear_cache(disk=True)`` to reset.
+   searches across PE sweeps are free after the first.
 
 The engine is *provably* result-identical to the reference brute force: it
 draws candidates from the same ``ndrange.tile_candidates`` lattice, keeps
@@ -51,10 +47,7 @@ asserts equality against the reference on all five op families.
 from __future__ import annotations
 
 import dataclasses
-import hashlib
-import json
 import math
-import os
 import threading
 from collections import OrderedDict
 from typing import Any, Callable, Mapping
@@ -99,89 +92,29 @@ def _caps_signature(caps: Mapping[str, int] | None) -> tuple:
 
 
 # ---------------------------------------------------------------------------
-# Layer 3: memoization (in-process LRU + optional on-disk JSON cache).
+# Layer 3: memoization (in-process LRU).
 # ---------------------------------------------------------------------------
 
 _LRU_MAXSIZE = 4096
 _lru: OrderedDict[tuple, Any] = OrderedDict()
 _lru_lock = threading.Lock()
-cache_stats = {"hits": 0, "misses": 0, "disk_hits": 0, "evictions": 0}
+cache_stats = {"hits": 0, "misses": 0, "evictions": 0}
 
 
-def _mirror_stats() -> None:
-    """Mirror the cache counters into the metrics registry as gauges (the
-    8 µs-warm claim's regression surface: bench_scheduler reports them)."""
-    from repro.obs import REGISTRY
-    for k, v in cache_stats.items():
-        REGISTRY.gauge(f"autotune_cache.{k}", v)
-
-
-def _disk_cache_dir() -> str | None:
-    if os.environ.get("REPRO_SCHED_DISK_CACHE", "0") not in ("1", "true", "yes"):
-        return None
-    return os.environ.get("REPRO_CACHE_DIR",
-                          os.path.join(".cache", "repro_scheduler"))
-
-
-def _disk_path(key: tuple) -> str | None:
-    root = _disk_cache_dir()
-    if root is None:
-        return None
-    h = hashlib.sha256(repr(key).encode()).hexdigest()[:32]
-    return os.path.join(root, f"{key[0]}_{h}.json")
-
-
-def reset_cache_stats() -> None:
-    """Zero the cache counters (tests and delta-based reporting)."""
-    with _lru_lock:
-        cache_stats.update(hits=0, misses=0, disk_hits=0, evictions=0)
-
-
-def clear_cache(*, disk: bool = False) -> None:
-    """Drop every memoized schedule/plan (and the on-disk cache if asked).
-    ``cache_stats`` counters survive — they are lifetime telemetry, not
-    cache contents (``reset_cache_stats`` zeroes them)."""
+def clear_cache() -> None:
+    """Drop every memoized schedule/plan.  ``cache_stats`` counters
+    survive — they are lifetime telemetry, not cache contents."""
     with _lru_lock:
         _lru.clear()
-    if disk:
-        root = os.environ.get("REPRO_CACHE_DIR",
-                              os.path.join(".cache", "repro_scheduler"))
-        if os.path.isdir(root):
-            for name in os.listdir(root):
-                if name.endswith(".json"):
-                    try:
-                        os.remove(os.path.join(root, name))
-                    except OSError:
-                        pass
 
 
-def _memo(key: tuple, compute: Callable[[], Any],
-          to_json: Callable[[Any], Any] | None = None,
-          from_json: Callable[[Any], Any] | None = None) -> Any:
-    """LRU + optional disk lookup around ``compute()``.
-
-    ``to_json``/``from_json`` serialize the value for the disk tier; when
-    omitted the value is only cached in memory.
-    """
+def _memo(key: tuple, compute: Callable[[], Any]) -> Any:
+    """LRU lookup around ``compute()``."""
     with _lru_lock:
         if key in _lru:
             _lru.move_to_end(key)
             cache_stats["hits"] += 1
             return _lru[key]
-    path = _disk_path(key) if to_json is not None else None
-    if path is not None and os.path.exists(path):
-        try:
-            with open(path) as f:
-                value = from_json(json.load(f))
-            cache_stats["disk_hits"] += 1
-            with _lru_lock:
-                _lru[key] = value
-                while len(_lru) > _LRU_MAXSIZE:
-                    _lru.popitem(last=False)
-                    cache_stats["evictions"] += 1
-            return value
-        except (OSError, ValueError, KeyError, TypeError):
-            pass  # corrupt entry: recompute and overwrite
     cache_stats["misses"] += 1
     value = compute()
     with _lru_lock:
@@ -189,15 +122,6 @@ def _memo(key: tuple, compute: Callable[[], Any],
         while len(_lru) > _LRU_MAXSIZE:
             _lru.popitem(last=False)
             cache_stats["evictions"] += 1
-    if path is not None:
-        try:
-            os.makedirs(os.path.dirname(path), exist_ok=True)
-            tmp = path + ".tmp"
-            with open(tmp, "w") as f:
-                json.dump(to_json(value), f)
-            os.replace(tmp, path)
-        except OSError:
-            pass  # disk tier is best-effort
     return value
 
 
@@ -339,15 +263,6 @@ def _search_tiles_vectorized(op: TensorOp, buf, caps, prefer_large: bool):
 # wrappers; call these directly for explicit engine use).
 # ---------------------------------------------------------------------------
 
-def _schedule_to_json(s) -> dict:
-    return dataclasses.asdict(s)
-
-
-def _schedule_from_json(d: dict):
-    from .tiling import TileSchedule
-    return TileSchedule(**d)
-
-
 def search_tiles_engine(op: TensorOp, buf, *,
                         caps: Mapping[str, int] | None = None,
                         prefer_large: bool = True):
@@ -367,7 +282,7 @@ def search_tiles_engine(op: TensorOp, buf, *,
                                           prefer_large=prefer_large)
         return _search_tiles_vectorized(op, buf, caps, prefer_large)
 
-    s = _memo(key, compute, _schedule_to_json, _schedule_from_json)
+    s = _memo(key, compute)
     # Fresh dicts per caller: the LRU entry is shared process-wide, and a
     # caller mutating schedule.tile/.grid in place must not poison it.
     return dataclasses.replace(s, op_name=op.name, tile=dict(s.tile),
@@ -384,11 +299,6 @@ def order_grid_engine(op: TensorOp, tile: Mapping[str, int]):
     """
     key = ("order", op_signature(op), _caps_signature(tile))
 
-    def from_json(d):
-        from .exchange import GridOrder
-        return GridOrder(tuple(d["order"]), d["resident_bytes_saved"],
-                         d["total_fetch_bytes"])
-
     def compute():
         # worst case over all permutations is the refetch-everything bound
         # (num_tiles * sum of footprints); past int64-exact territory the
@@ -400,7 +310,7 @@ def order_grid_engine(op: TensorOp, tile: Mapping[str, int]):
             return order_grid_for_sharing_reference(op, tile)
         return _order_grid_vectorized(op, tile)
 
-    return _memo(key, compute, _schedule_to_json, from_json)
+    return _memo(key, compute)
 
 
 def _order_grid_vectorized(op: TensorOp, tile):
@@ -451,13 +361,6 @@ def plan_mesh_exchange_engine(op: TensorOp, tile: Mapping[str, int],
     key = ("mesh", op_signature(op), _caps_signature(tile), mesh_shape,
            share_rows, share_cols, row_span_cap, col_span_cap)
 
-    def from_json(d):
-        from .exchange import ExchangePlan
-        return ExchangePlan(tuple(d["mesh_shape"]), d["row_axis"],
-                            d["col_axis"], d["fetch_bytes"],
-                            d["fetch_bytes_unshared"], d["fifo_hop_bytes"],
-                            d["waves"])
-
     def compute():
         from .exchange import plan_mesh_exchange_reference
         return plan_mesh_exchange_reference(
@@ -465,4 +368,4 @@ def plan_mesh_exchange_engine(op: TensorOp, tile: Mapping[str, int],
             share_cols=share_cols, row_span_cap=row_span_cap,
             col_span_cap=col_span_cap)
 
-    return _memo(key, compute, _schedule_to_json, from_json)
+    return _memo(key, compute)

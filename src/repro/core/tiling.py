@@ -110,7 +110,7 @@ def search_tiles_reference(op: TensorOp, buf: BufferSpec = TEU_BUFFER, *,
                            caps: Mapping[str, int] | None = None,
                            prefer_large: bool = True) -> TileSchedule:
     """Brute-force reference for ``search_tiles`` (kept for equivalence
-    tests and ``benchmarks/bench_scheduler.py --reference`` timing)."""
+    tests)."""
     best: TileSchedule | None = None
     best_key = None
     for tile in enumerate_tiles(op, caps=caps):

@@ -2,11 +2,11 @@
 
 Conventions:
   * params are plain dicts of jnp arrays; layer stacks carry a leading layer
-    axis and are traversed with jax.lax.scan (compact HLO for the dry-run).
+    axis and are traversed with jax.lax.scan (compact HLO at any depth).
   * activations default to bf16; params bf16; accumulations f32.
   * attention is grouped (GQA) and supports qk-norm, qkv-bias, causal and
-    sliding-window masks. The XLA path is used for lowering/dry-run (CPU
-    container); the Pallas flash kernels (repro.kernels) are the TPU path,
+    sliding-window masks. The XLA path is used on the CPU; the Pallas
+    flash kernels (repro.kernels) are the TPU path,
     selected via ``impl='pallas'``.
 """
 from __future__ import annotations
@@ -416,7 +416,7 @@ def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Per-(token, head) symmetric int8 KV quantization.
 
     x: (..., Dh) -> (int8 same shape, f32 scale (...,)). Halves decode-cache
-    HBM vs bf16 — what lets the 32B-param decode_32k cell fit a v5e pod."""
+    HBM vs bf16."""
     xf = x.astype(jnp.float32)
     amax = jnp.max(jnp.abs(xf), axis=-1)
     scale = jnp.maximum(amax / 127.0, 1e-8)
@@ -435,8 +435,8 @@ def decode_attention(q, k_cache, v_cache, lengths, k_scale=None,
     lengths: (B,).
 
     Long caches process in chunks with an online softmax so quantized
-    caches dequantize ONE chunk at a time — the full-cache f32 dequant temp
-    was the qwen1.5-32b decode_32k capacity blocker (§Perf next-steps)."""
+    caches dequantize ONE chunk at a time — a full-cache f32 dequant temp
+    would cost 4x the int8 cache's HBM."""
     B, _, H, Dh = q.shape
     S, Hkv = k_cache.shape[1], k_cache.shape[2]
     G = H // Hkv

@@ -3,7 +3,7 @@
 The SSD chunked form IS a chain of dense GEMMs (intra-chunk quadratic block +
 low-rank inter-chunk state passing), which is exactly the workload family the
 paper's tiling methodology targets; the chunk size plays the TEU-tile role.
-Sub-quadratic in sequence length -> this arch runs the long_500k shape.
+Sub-quadratic in sequence length.
 
 Layers scan-stacked; decode keeps O(1) state (conv window + SSM state), so
 a "500k-token KV cache" is a few MB of state.
@@ -38,7 +38,7 @@ class Mamba2Config:
     remat: bool = True
     # sequence parallelism hurts here: d_model=1024 gives tiny per-device
     # shards and GSPMD re-gathers around the SSD chunk scans (2.5x flops,
-    # 5x traffic measured) — see EXPERIMENTS.md SPerf, lesson L3.
+    # 5x traffic measured).
     sp: bool = False
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16

@@ -3,7 +3,7 @@
 Block pattern repeats (recurrent, recurrent, local-attention); 38 layers =
 12 full groups + 2 trailing recurrent blocks. The RG-LRU is a gated linear
 recurrence evaluated with an associative scan (train/prefill) or a single
-state update (decode) — sub-quadratic, so this arch runs long_500k. Local
+state update (decode) — sub-quadratic in sequence length. Local
 attention is MQA (kv=1) with a 2048 sliding window, so its decode cache is
 window-bounded, not seq_len-bounded.
 """

@@ -4,7 +4,7 @@ Covers qwen3-4b (qk_norm), qwen2.5-14b / qwen1.5-32b (QKV bias), yi-9b,
 internvl2-26b (vision-prefix backbone; the ViT frontend is a stub per the
 assignment — ``vision_embeds`` arrive precomputed), granite-moe and olmoe
 (MoE MLPs). Layers are stacked on a leading axis and traversed with
-jax.lax.scan so the HLO stays compact for the 512-device dry-run.
+jax.lax.scan so the HLO stays compact at any depth.
 """
 from __future__ import annotations
 

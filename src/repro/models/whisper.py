@@ -1,7 +1,7 @@
 """Whisper-medium style encoder-decoder (arXiv:2212.04356).
 
-The conv audio frontend is a STUB per the assignment: ``input_specs()``
-supplies precomputed frame embeddings (B, S_enc, D) directly to the encoder.
+The conv audio frontend is a STUB: the caller supplies precomputed frame
+embeddings (B, S_enc, D) directly to the encoder.
 Encoder: bidirectional MHA + GELU MLP, sinusoidal positions. Decoder: causal
 self-attention + cross-attention over encoder output, learned positions,
 tied output embedding. LayerNorm (with bias) throughout, pre-norm.

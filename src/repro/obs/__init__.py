@@ -1,21 +1,19 @@
-"""Unified telemetry: metrics registry + span tracer + traffic accounting.
+"""Unified telemetry: metrics registry + span tracer.
 
-Three layers (see docs/ARCHITECTURE.md "Observability"):
+Two layers (see docs/ARCHITECTURE.md "Observability"):
 
 1. :mod:`repro.obs.metrics` — labeled counters/gauges/histograms with one
    process-wide :data:`~repro.obs.metrics.REGISTRY` that absorbs the
    repo's scattered ad-hoc stats;
 2. :mod:`repro.obs.trace` — nested span tracer (virtual-clock compatible)
    with a Chrome trace-event JSON exporter perfetto can load; each span
-   is also mirrored into the JAX profiler's trace when JAX is loaded;
-3. :mod:`repro.obs.roofline_live` — observed-vs-predicted traffic rows
-   that close the loop on the paper's fetch-reduction claims at runtime.
+   is also mirrored into the JAX profiler's trace when JAX is loaded.
 
 The :class:`Telemetry` facade bundles a tracer with the registry and a
 single ``enabled`` switch.  The GLOBAL default is DISABLED: hot paths
 (the serving tick loop) check ``telemetry.enabled`` once and skip every
 span/counter, so an untelemetered serve pays only a handful of attribute
-reads per tick (< 2% tick time — asserted by the smoke benchmark).
+reads per tick.
 ``obs.enable()`` flips the global on (the launchers do this when
 ``--trace-out``/``--metrics-out`` is passed); components that cannot be
 handed a Telemetry explicitly (kernel wrappers, checkpoint manager) reach

@@ -92,20 +92,6 @@ def param_specs(arch_kind: str, params_shape: Any, mesh: Mesh) -> Any:
     return jax.tree_util.tree_map_with_path(spec_for, params_shape)
 
 
-def batch_specs(shape_kind: str, mesh: Mesh) -> dict[str, P]:
-    """Input shardings for the train/serve step batches."""
-    d = _data_axes(mesh)
-    if shape_kind == "long":        # global_batch=1: shard sequence (SP)
-        return {
-            "tokens": P(None, d),
-            "labels": P(None, d),
-        }
-    return {
-        "tokens": P(d, None),
-        "labels": P(d, None),
-    }
-
-
 def cache_specs(mesh: Mesh, *, kv_heads: int, head_dim: int,
                 long_context: bool = False) -> dict[str, P]:
     """KV cache (L, B, S, Hkv, Dh) shardings."""

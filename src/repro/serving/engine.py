@@ -132,8 +132,8 @@ def _pow2_at_least(n: int, lo: int = 1) -> int:
 
 
 # Module-level jits shared by every engine instance (a per-engine closure
-# would give each engine its own compile cache, so benchmarks/tests that
-# build fresh engines over the same bundle would re-trace identical
+# would give each engine its own compile cache, so callers that build
+# fresh engines over the same bundle would re-trace identical
 # shapes).  ``step`` is the bundle's paged_step, static so its identity
 # keys the cache.  Each donates the pool it is given, so a step or a page
 # copy updates the pool in place instead of writing a second one; the
@@ -298,7 +298,7 @@ class ServingEngine:
         then one device step over the whole slot pool (prefill chunks and
         decode rows share it in the paged modes).  The event-loop API —
         callers interleave ``submit`` and ``step`` to serve an open-ended
-        arrival stream (see benchmarks/bench_traffic.py)."""
+        arrival stream."""
         if self.cfg.kv_mode == "dense":
             self._step_dense()
         else:
@@ -542,8 +542,8 @@ class ServingEngine:
         # live KV traffic: token-exact attended context (the paper's
         # global-buffer level) and page-granular pool reads (DRAM level),
         # accumulated in _exec_rows.  Plain int adds — always on; the
-        # roofline accountant compares them against the closed-form
-        # prediction (obs.roofline_live.predict_paged_decode_traffic).
+        # benchmark's mfu metrics read them, and tests/test_obs.py holds
+        # them to a closed-form prediction.
         self._traffic = dict.fromkeys(_TRAFFIC_COUNTERS, 0)
 
     def _pages_view(self, max_tokens: int) -> int:

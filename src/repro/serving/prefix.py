@@ -107,7 +107,7 @@ class RadixPrefixCache:
         self.page_size = kv.cfg.page_size
         self.root = _Node((), BlockPoolKV.TRASH, None)
         self._clock = itertools.count(1)
-        # counters (surfaced by stats(); bench_traffic reports them)
+        # counters (surfaced by stats())
         self.lookups = 0
         self.hits = 0
         self.matched_tokens = 0

@@ -1,8 +1,5 @@
 """Scheduler engine (repro.core.autotune): equivalence vs brute force,
-pruning correctness, memoization keying, and the on-disk cache tier."""
-import dataclasses
-import os
-
+pruning correctness and memoization keying."""
 import pytest
 
 from repro.core import (TEU_BUFFER, BufferSpec, attention_scores_op,
@@ -122,29 +119,7 @@ def test_buffer_and_caps_in_cache_key():
     assert cache_stats["misses"] == misses + 2
 
 
-def test_disk_cache_roundtrip(tmp_path, monkeypatch):
-    monkeypatch.setenv("REPRO_SCHED_DISK_CACHE", "1")
-    monkeypatch.setenv("REPRO_CACHE_DIR", str(tmp_path))
-    op = conv2d_op(32, 16, 14, 14, 3, 3)
-    s1 = search_tiles(op, TEU_BUFFER)
-    assert any(p.suffix == ".json" for p in tmp_path.iterdir())
-    clear_cache()                      # drop the LRU, keep the disk tier
-    s2 = search_tiles(op, TEU_BUFFER)
-    assert s2 == s1
-    assert cache_stats["disk_hits"] == 1
-    from repro.core.autotune import clear_cache as cc
-    cc(disk=True)
-    assert not any(p.suffix == ".json" for p in tmp_path.iterdir())
-
-
 def test_engine_infeasible_raises_like_reference():
     op = matmul_op(8, 8, 8)
     with pytest.raises(ValueError):
         search_tiles(op, BufferSpec(input_bytes=4, psum_bytes=1))
-
-
-def test_schedule_is_plain_dataclass_roundtrip():
-    """Disk serialization preserves every TileSchedule field exactly."""
-    from repro.core.autotune import _schedule_from_json, _schedule_to_json
-    s = search_tiles(conv2d_op(16, 8, 12, 12, 3, 3), TEU_BUFFER)
-    assert _schedule_from_json(_schedule_to_json(s)) == s
