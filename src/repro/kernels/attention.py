@@ -45,6 +45,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -57,6 +58,18 @@ NEG_INF = -1e30  # avoid nan from (-inf) - (-inf)
 # broadcast across one lane-dense 128-wide tile, (BH, S, LANES), and the
 # wrappers slice or broadcast it back to (BH, S).
 LANES = 128
+
+# The names under which a trainable attention's forward hands its output
+# and logsumexp to its backward.  A remat policy that saves both
+# (``models.transformer.forward``) leaves the backward nothing of the
+# attention forward to recompute; the XLA attention paths, which have no
+# VJP of their own, name their output alone (``models.layers.attention``).
+OUT_NAME, LSE_NAME = "attn_out", "attn_lse"
+
+
+def name_residuals(o: jax.Array, lse: jax.Array):
+    """``(o, lse)`` under :data:`OUT_NAME` and :data:`LSE_NAME`."""
+    return checkpoint_name(o, OUT_NAME), checkpoint_name(lse, LSE_NAME)
 
 
 def _out_struct(shape, dtype, *like) -> jax.ShapeDtypeStruct:
@@ -577,14 +590,16 @@ class FlashSpec:
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def flash_attention_train(spec: FlashSpec, q, k, v):
     """Differentiable fused flash attention: q (BH, Sq, D), k/v (BHkv, Sk,
-    D).  Forward saves only (o, lse); backward is the two Pallas re-stream
-    kernels above — the default trainable attention path on TPU."""
+    D).  Forward saves only (o, lse), named by :func:`name_residuals`;
+    backward is the two Pallas re-stream kernels above — the default
+    trainable attention path on TPU."""
     o, _ = flash_attention_fwd_pallas(q, k, v, **spec.kw())
     return o
 
 
 def _flash_train_fwd(spec: FlashSpec, q, k, v):
-    o, lse = flash_attention_fwd_pallas(q, k, v, **spec.kw())
+    o, lse = name_residuals(*flash_attention_fwd_pallas(q, k, v,
+                                                        **spec.kw()))
     return o, (q, k, v, o, lse)
 
 

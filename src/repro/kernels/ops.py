@@ -134,7 +134,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     B, Hq, Sq, Dh = q.shape
     _, Hkv, Sk, _ = k.shape
     if block_q is None or block_k is None:
-        bq, bk = attention_block_shapes(Sq, Sk, Dh)
+        bq, bk = attention_block_shapes(Sq, Sk, Dh,
+                                        itemsize=q.dtype.itemsize)
         block_q = block_q or bq
         block_k = block_k or bk
     block_q = min(block_q, Sq)

@@ -335,7 +335,16 @@ def _attention_ring(q, k, v, *, causal, window, ring: str | None = None):
                   P(dspec, None, None, None)),
         out_specs=P(dspec, "model", None, None),
     )
-    return fn(q, k, v)
+    return _named_out(fn(q, k, v))
+
+
+def _named_out(o):
+    """An XLA attention path's output under the name a remat policy keeps
+    (the flash and ring VJPs name their own output and logsumexp)."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from repro.kernels.attention import OUT_NAME
+    return checkpoint_name(o, OUT_NAME)
 
 
 def _shard_attn_inputs(q, k, v):
@@ -386,7 +395,11 @@ def attention(q, k, v, *, causal=True, window=None, impl=None,
     shard_map (ppermute ring / replicated k/v, per the ring policy) or
     double-blocked flash-style scan for long ones.  ``impl`` overrides
     the flash policy ('pallas' | 'xla'; None/'auto' resolves via
-    REPRO_FLASH_ATTN); ``ring`` overrides the ring-policy mode."""
+    REPRO_FLASH_ATTN); ``ring`` overrides the ring-policy mode.
+
+    Every path leaves what its backward reads under the names of
+    ``kernels.attention``, once: the flash and ring VJPs their output and
+    logsumexp, the XLA paths their output."""
     if impl in (None, "auto", "pallas", "xla"):
         mode = _flash_mode(q.shape[1], k.shape[1],
                            None if impl in (None, "auto") else impl)
@@ -406,10 +419,11 @@ def attention(q, k, v, *, causal=True, window=None, impl=None,
         if out is not None:
             return out
         q, k, v = _shard_attn_inputs(q, k, v)
-        return _attention_blocked(q, k, v, causal=causal, window=window)
+        return _named_out(_attention_blocked(q, k, v, causal=causal,
+                                             window=window))
     q, k, v = _shard_attn_inputs(q, k, v)
-    return _grouped_scores_full(q, k, v, causal=causal, window=window,
-                                q_offset=q_offset)
+    return _named_out(_grouped_scores_full(q, k, v, causal=causal,
+                                           window=window, q_offset=q_offset))
 
 
 def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:

@@ -148,12 +148,13 @@ def _block_train(cfg: TransformerConfig, x, lp, positions):
         h = gather_seq(rms_norm(x, lp["ln1"], cfg.norm_eps))
         q, k, v = _qkv(cfg, lp, h, positions)
     with jax.named_scope("attention"):
+        # every attention path names what its backward reads (the
+        # output, and on the flash and ring VJPs the logsumexp), once;
+        # ``forward``'s remat policy saves exactly that, so the backward
+        # recomputes the qkv projections and never the attention forward
+        # (§Perf B1)
         o = attention(q, k, v, causal=True, window=cfg.window,
                       impl=cfg.attn_impl, ring=cfg.ring_attn)
-        # saved by the remat policy: backward reuses the attention output
-        # instead of re-streaming the whole flash pipeline (§Perf B1)
-        from jax.ad_checkpoint import checkpoint_name
-        o = checkpoint_name(o, "attn_out")
         # Megatron-SP residual stream: the carry x stays SEQUENCE-SHARDED
         # and only the deltas are resharded before the add — GSPMD then
         # lowers the wo / w_down partial-sum contractions as reduce-scatter
@@ -187,10 +188,11 @@ def forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         return (x, aux + a), None
 
     if cfg.remat:
+        from repro.kernels.attention import LSE_NAME, OUT_NAME
         body = jax.checkpoint(
             body,
             policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_out"))
+                OUT_NAME, LSE_NAME))
     (x, aux), _ = jax.lax.scan(body, (x, 0.0), params["layers"])
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["ln_f"], cfg.norm_eps)

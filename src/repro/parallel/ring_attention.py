@@ -10,7 +10,10 @@ ever materializes and only one shard is in flight per step.
 Forward (per ``model``-axis device, ring of ``m``):
   q_l: (B, S/m, H, Dh) local rows; k_l/v_l: this device's own sequence
   shard.  ``m`` hops of fold-then-permute.  The custom VJP saves ONLY
-  ``(o, logsumexp)`` — O(S/m · H · Dh) per device, independent of ``m``.
+  ``(o, logsumexp)`` — O(S/m · H · Dh) per device, independent of ``m`` —
+  under the names a layer's remat policy keeps
+  (``kernels.attention.name_residuals``), so a rematerialised layer's
+  backward does not run the forward ring again to get them back.
 
 Backward (a second ring pass with the same hop schedule):
   each hop RECOMPUTES the visiting shard's score tile from
@@ -378,7 +381,8 @@ def _ring_attn(spec: _RingSpec, q, k, v):
 
 
 def _ring_attn_fwd(spec: _RingSpec, q, k, v):
-    o, lse = _shard_fwd(spec, q, k, v)
+    from repro.kernels.attention import name_residuals
+    o, lse = name_residuals(*_shard_fwd(spec, q, k, v))
     return o, (q, k, v, o, lse)
 
 

@@ -416,3 +416,74 @@ assert any(n.startswith("jit(") and "transpose(" not in n for n in hops), hops[:
 assert any("transpose(" in n for n in hops), hops[:4]
 print("scopes ok", len(hops))
 """, devices=4)
+
+
+def test_remat_layer_keeps_ring_residuals():
+    """A two-layer remat'd qwen3-4b smoke ``forward`` on a (1, 4) mesh at
+    4096 tokens, where attention takes the ring: the ring's VJP names its
+    output and logsumexp and the layer's remat policy keeps both, so the
+    compiled gradient runs one forward ring and one backward ring (6
+    ring collective-permutes, not 8), in temporaries no larger than the
+    layer that saved the output alone plus the logsumexp's bytes.  Loss
+    and gradients equal those of the same forward without remat."""
+    _run("""
+import dataclasses, functools, re
+import jax, jax.numpy as jnp, numpy as np
+from jax.ad_checkpoint import checkpoint_name
+from repro.configs import get_bundle
+from repro.kernels import attention as katt
+from repro.launch.mesh import make_host_mesh
+from repro.models import transformer
+from repro.training import loss_fn
+bundle = get_bundle("qwen3-4b", smoke=True).on_mesh(make_host_mesh(4))
+cfg = bundle.cfg
+assert cfg.remat and cfg.n_layers == 2
+params = jax.jit(bundle.init_params,
+                 out_shardings=bundle.param_shardings())(jax.random.PRNGKey(0))
+S = 4096
+tok = jax.random.randint(jax.random.PRNGKey(1), (1, S + 1), 0, cfg.vocab)
+batch = {"tokens": tok[:, :-1], "labels": tok[:, 1:]}
+
+def compiled(b):
+    jax.clear_caches()
+    grad = jax.value_and_grad(functools.partial(loss_fn, b.forward),
+                              has_aux=True)
+    return jax.jit(grad).lower(params, batch).compile()
+
+def ring_permutes(comp):
+    return sum(1 for ln in comp.as_text().splitlines()
+               if re.search(r" collective-permute(-start)?\\(", ln)
+               and "/ring_hop/" in ln)
+
+def temp(comp):
+    return comp.memory_analysis().temp_size_in_bytes
+
+named = compiled(bundle)
+attention, name_residuals = transformer.attention, katt.name_residuals
+# the output named once, outside the ring's VJP: lse is not saveable
+transformer.attention = lambda *a, **kw: checkpoint_name(
+    attention(*a, **kw), katt.OUT_NAME)
+katt.name_residuals = lambda o, lse: (o, lse)
+before = compiled(bundle)
+# the output named twice, inside the VJP and after it
+katt.name_residuals = name_residuals
+doubled = compiled(bundle)
+transformer.attention = attention
+
+lse_bytes = cfg.n_layers * cfg.n_heads * (S // 4) * 4   # one device's
+assert (ring_permutes(before), ring_permutes(named)) == (8, 6), (
+    ring_permutes(before), ring_permutes(named))
+assert temp(named) <= temp(before) + lse_bytes, (temp(named), temp(before))
+# detector sanity: a doubly saved output exceeds the bound
+assert temp(doubled) > temp(before) + lse_bytes, (temp(doubled), temp(before))
+
+(loss, _), grads = named(params, batch)
+plain = dataclasses.replace(bundle, cfg=dataclasses.replace(cfg, remat=False))
+(loss_p, _), grads_p = compiled(plain)(params, batch)
+np.testing.assert_allclose(float(loss), float(loss_p), rtol=1e-6)
+for (path, g), g_p in zip(jax.tree_util.tree_leaves_with_path(grads),
+                          jax.tree.leaves(grads_p)):
+    np.testing.assert_allclose(np.asarray(g), np.asarray(g_p), rtol=1e-5,
+                               atol=1e-7, err_msg=jax.tree_util.keystr(path))
+print("ring residuals kept:", temp(before), temp(named), temp(doubled))
+""", devices=4)

@@ -267,3 +267,91 @@ def _ring_fused_hop_compiles(topo, monkeypatch, dtype, S):
     with compat.set_mesh(mesh):
         _assert_mosaic(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
     assert fused_hops() > before
+
+
+def _attention_unnamed(monkeypatch):
+    """The layer as it was before the attention VJPs named their
+    residuals: the flash and ring forwards hand ``(o, lse)`` to their
+    backward unnamed, and the layer names the attention's output after
+    the VJP returns it, so the remat policy can keep ``o`` but not
+    ``lse`` and the backward runs the attention forward again."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from repro.models import transformer
+    attention = transformer.attention
+    monkeypatch.setattr(katt, "name_residuals", lambda o, lse: (o, lse))
+    monkeypatch.setattr(transformer, "attention", lambda *a, **kw:
+                        checkpoint_name(attention(*a, **kw), katt.OUT_NAME))
+
+
+def _carried(text: str, n_elems: int) -> list[str]:
+    """The dtypes of the arrays of ``n_elems`` elements in the state of
+    the compiled program's while loop that carries most of them."""
+    most: list[str] = []
+    for line in text.splitlines():
+        if " while(" not in line:
+            continue
+        state = line.split(" while(")[0]
+        hits = [dt for dt, dims in re.findall(r"(\w+)\[([\d,]+)\]", state)
+                if math.prod(int(d) for d in dims.split(",")) == n_elems]
+        most = max(most, hits, key=len)
+    return most
+
+
+@pytest.mark.parametrize("path", ["ring", "flash"])
+def test_remat_step_keeps_attention_residuals(topo, monkeypatch, path):
+    """Two remat'd float32 qwen3-4b layers at full width, the loss's
+    gradient compiled for a described v5e: ``ring`` on the (1, 4) mesh of
+    the ``qwen3-4b-16l.ring-train`` cell at its 8192 tokens, ``flash``
+    on one chip at 2048.  The attention VJPs name their output and
+    logsumexp, so the backward does not run the forward kernel again:
+    three attention kernels where the layer that saved the output alone
+    has four (the ring's under ``ring_hop``).  The layer scan carries the
+    output once, in float32: before, XLA kept it in bfloat16, since only
+    the output projection read it, so the saved bytes per element rise
+    from 2 to 4."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_bundle
+    from repro.runtime import compat
+    from repro.training import loss_fn
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    full = get_bundle("qwen3-4b", smoke=False)
+    n_layers = 2
+    bundle = dataclasses.replace(full, cfg=dataclasses.replace(
+        full.cfg, n_layers=n_layers, dtype=jnp.float32))
+    if path == "ring":
+        S, chips = 8192, 4
+        mesh = compat.make_mesh((1, chips), ("data", "model"),
+                                devices=topo.devices)
+        bundle = bundle.on_mesh(mesh)
+        shardings = bundle.param_shardings()
+        rows = NamedSharding(mesh, P())
+    else:
+        S, chips = 2048, 1
+        rows = SingleDeviceSharding(topo.devices[0])
+        shardings = jax.tree.map(lambda _: rows, bundle.abstract_params())
+    params = jax.tree.map(lambda s, sh: _struct(s.shape, s.dtype, sh),
+                          bundle.abstract_params(), shardings)
+    tokens = _struct((1, S), jnp.int32, rows)
+    grad = jax.value_and_grad(functools.partial(loss_fn, bundle.forward),
+                              has_aux=True)
+    out_elems = n_layers * (S // chips) * H * DH   # one chip's saved output
+
+    def compile_step():
+        jax.clear_caches()          # the flash wrapper's trace is cached
+        text = jax.jit(grad).lower(
+            params, {"tokens": tokens, "labels": tokens}).compile().as_text()
+        calls = [ln for ln in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        if path == "ring":
+            calls = [ln for ln in calls if "/ring_hop/" in ln]
+        return len(calls), _carried(text, out_elems)
+
+    calls, saved = compile_step()
+    with monkeypatch.context() as m:
+        _attention_unnamed(m)
+        calls_before, saved_before = compile_step()
+    # forward, forward again, dq and dk/dv before; forward, dq, dk/dv now
+    assert (calls_before, calls) == (4, 3)
+    assert (saved_before, saved) == (["bf16"], ["f32"])
